@@ -14,19 +14,22 @@ Three measurements into ``BENCH_serving.json`` (all on the deterministic
   workload served request-at-a-time (``max_batch=1``, buckets ``(1,)``)
   vs micro-batched; micro-batching must sustain >= 2x the frames/s.
 * **device-pool ablation** (schema v2) — the same saturating workload
-  through a ``devices=1`` vs ``devices=4`` server, run in a subprocess
-  with ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the
-  device count is fixed at jax init). The **gated** number uses an
+  through a ``devices=1`` vs ``devices=4`` server, in this process, on
+  its own local devices: four chips of one host, or on CPU four virtual
+  devices (``XLA_FLAGS=--xla_force_host_platform_device_count=4``, set
+  before JAX starts — ``benchmarks/run.py`` does so). No child process is
+  started: a process that has touched JAX holds the chip. With fewer than
+  four local devices the run fails. The **gated** number uses an
   *emulated* device: the ``Hooks.execute`` seam replaces the XLA call
   with a GIL-releasing sleep proportional to the padded bucket, so the
   measurement isolates the host runtime's ability to keep N devices
   fed — which is the thing the pool exists to prove, and the honest
   analogue of the paper's optical device computing off-host. (Real-XLA
-  virtual devices share this machine's CPU core, so their scaling is
-  reported alongside but not gated — a 1-core host cannot physically
-  run 4 compute-bound XLA programs faster than 1.)
+  virtual devices share this machine's CPU cores, so their scaling is
+  reported alongside but not gated.)
 
-Run: ``PYTHONPATH=src python -m benchmarks.bench_serving [--quick]``.
+Run: ``XLA_FLAGS=--xla_force_host_platform_device_count=4 PYTHONPATH=src
+python -m benchmarks.bench_serving [--quick]``.
 """
 
 from __future__ import annotations
@@ -34,7 +37,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 from pathlib import Path
@@ -48,7 +50,6 @@ from repro.core.quant import W4A4
 
 SCHEMA_VERSION = 2
 OUT_PATH = Path(__file__).resolve().parent / "BENCH_serving.json"
-ROOT = Path(__file__).resolve().parent.parent
 LOAD_FRACTIONS = (0.25, 0.5, 1.0, 1.5)
 PROGRAMS = ("lenet", "edge_detect")
 POOL_DEVICES = 4
@@ -79,16 +80,16 @@ def _server(progs, max_batch: int, buckets=None,
     return srv.start(warm=True)
 
 
-def _pool_child(quick: bool = False) -> None:
-    """Subprocess body for the device-pool ablation: measures devices=1
-    vs devices=4 capacity and prints one JSON line. Run via
-    ``--pool-child`` under ``--xla_force_host_platform_device_count=4``.
-    """
+def _pool_ablation(quick: bool = False) -> dict:
+    """devices=1 vs devices=4 capacity, emulated and real XLA, on this
+    process's own local devices (at least ``POOL_DEVICES`` of them)."""
     n_requests = 96 if quick else 240
     if len(jax.local_devices()) < POOL_DEVICES:
-        print(json.dumps({"skipped": f"only {len(jax.local_devices())} "
-                                     f"local device(s)"}))
-        return
+        raise RuntimeError(
+            f"the device-pool ablation needs {POOL_DEVICES} local devices, "
+            f"JAX sees {len(jax.local_devices())}: run on a "
+            f"{POOL_DEVICES}-chip host, or on CPU with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={POOL_DEVICES}")
     prog = _program("lenet")
     frames = _pool(prog)
     options = repro.Options(scheme=W4A4, backend="reference")
@@ -118,7 +119,7 @@ def _pool_child(quick: bool = False) -> None:
     em4, st4 = capacity(POOL_DEVICES, hooks)
     x1, _ = capacity(1, warm=True)
     x4, _ = capacity(POOL_DEVICES, warm=True)
-    print(json.dumps({
+    return {
         "devices": POOL_DEVICES,
         "n_requests": n_requests,
         "per_frame_ms": POOL_PER_FRAME_MS,
@@ -128,27 +129,7 @@ def _pool_child(quick: bool = False) -> None:
         "xla": {"pool1_fps": x1, "pool4_fps": x4,
                 "speedup": x4 / max(x1, 1e-9),
                 "host_cores": os.cpu_count()},
-    }))
-
-
-def _pool_ablation(quick: bool = False) -> dict:
-    """Run :func:`_pool_child` in a 4-virtual-device subprocess."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{POOL_DEVICES}").strip()
-    env["PYTHONPATH"] = (str(ROOT / "src") + os.pathsep +
-                         env.get("PYTHONPATH", ""))
-    cmd = [sys.executable, "-m", "benchmarks.bench_serving", "--pool-child"]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=ROOT, timeout=900)
-    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    if proc.returncode != 0 or not lines:
-        return {"skipped": f"pool child failed (rc={proc.returncode}): "
-                           f"{proc.stderr.strip()[-500:]}"}
-    return json.loads(lines[-1])
+    }
 
 
 def run(csv: bool = True, quick: bool = False,
@@ -225,27 +206,23 @@ def run(csv: bool = True, quick: bool = False,
         f"batch1_fps={rep1.achieved_fps:.0f};"
         f"microbatch_fps={repN.achieved_fps:.0f};speedup={speedup:.2f}x")
 
-    # -- device-pool ablation (4 virtual devices, subprocess) --------------
+    # -- device-pool ablation (4 local devices, this process) --------------
     pool_abl = _pool_ablation(quick)
-    if "skipped" in pool_abl:
-        out_lines.append(f"bench_serving.pool_ablation,0,"
-                         f"skipped={pool_abl['skipped'][:80]}")
-    else:
-        em = pool_abl["emulated"]
-        out_lines.append(
-            f"bench_serving.pool_ablation.emulated,"
-            f"{1e6 / max(em['pool4_fps'], 1e-9):.0f},"
-            f"pool1_fps={em['pool1_fps']:.0f};"
-            f"pool4_fps={em['pool4_fps']:.0f};"
-            f"speedup={em['speedup']:.2f}x;steals={em['steals']}")
-        xl = pool_abl["xla"]
-        out_lines.append(
-            f"bench_serving.pool_ablation.xla,"
-            f"{1e6 / max(xl['pool4_fps'], 1e-9):.0f},"
-            f"pool1_fps={xl['pool1_fps']:.0f};"
-            f"pool4_fps={xl['pool4_fps']:.0f};"
-            f"speedup={xl['speedup']:.2f}x;"
-            f"host_cores={xl['host_cores']} (ungated)")
+    em = pool_abl["emulated"]
+    out_lines.append(
+        f"bench_serving.pool_ablation.emulated,"
+        f"{1e6 / max(em['pool4_fps'], 1e-9):.0f},"
+        f"pool1_fps={em['pool1_fps']:.0f};"
+        f"pool4_fps={em['pool4_fps']:.0f};"
+        f"speedup={em['speedup']:.2f}x;steals={em['steals']}")
+    xl = pool_abl["xla"]
+    out_lines.append(
+        f"bench_serving.pool_ablation.xla,"
+        f"{1e6 / max(xl['pool4_fps'], 1e-9):.0f},"
+        f"pool1_fps={xl['pool1_fps']:.0f};"
+        f"pool4_fps={xl['pool4_fps']:.0f};"
+        f"speedup={xl['speedup']:.2f}x;"
+        f"host_cores={xl['host_cores']} (ungated)")
 
     payload = {
         "schema_version": SCHEMA_VERSION,
@@ -266,7 +243,4 @@ def run(csv: bool = True, quick: bool = False,
 
 
 if __name__ == "__main__":
-    if "--pool-child" in sys.argv:
-        _pool_child(quick="--quick" in sys.argv)
-    else:
-        run(quick="--quick" in sys.argv)
+    run(quick="--quick" in sys.argv)

@@ -33,7 +33,8 @@ executor), depthwise channel equality, and act/pool/upsample vocabulary.
 strip geometry and fused-segment footprints are re-derived here from
 first principles — the halo recurrence ``rows_in = (rows_out - 1) *
 stride + kernel`` (pool expands first), padded-input/output/weight byte
-counts — and compared against what ``select_conv_strategy`` /
+counts priced as the TPU lays VMEM out (last dim to 128 lanes, the one
+before to 8 sublanes) — and compared against what ``select_conv_strategy`` /
 ``select_fused_segments`` recorded in the plan. The heuristic deciding a
 *policy* differently is fine; the heuristic recording geometry that does
 not cover the output, or selecting a segment that is not legally fusable,
@@ -280,14 +281,20 @@ def _geom_out_hw(g) -> Tuple[int, int]:
     return h, w
 
 
+def _vmem_tile_bytes(rows: int, sublanes: int, lanes: int) -> int:
+    """f32 bytes of a [rows, sublanes, lanes] VMEM buffer as the TPU lays it
+    out: the last dim rounded up to 128 lanes, the one before to 8."""
+    return rows * (-(-sublanes // 8) * 8) * (-(-lanes // 128) * 128) * 4
+
+
 def _geom_stage_bytes(g) -> int:
-    """f32 working set of one fused stage: padded input + output + weights
-    (independent restatement of ``ChainGeom.stage_bytes``)."""
+    """f32 working set of one fused stage: padded input + output + weights,
+    tile-padded (independent restatement of ``ChainGeom.stage_bytes``)."""
     (plo, phi), (qlo, qhi) = g.pads
     h_out, w_out = _geom_out_hw(g)
-    in_b = (g.h_in + plo + phi) * (g.w_in + qlo + qhi) * g.c_in * 4
-    out_b = h_out * w_out * g.c_out * 4
-    w_b = g.kernel * g.kernel * (g.c_in // g.groups) * g.c_out * 4
+    in_b = _vmem_tile_bytes(g.h_in + plo + phi, g.w_in + qlo + qhi, g.c_in)
+    out_b = _vmem_tile_bytes(h_out, w_out, g.c_out)
+    w_b = _vmem_tile_bytes(g.kernel * g.kernel, g.c_in // g.groups, g.c_out)
     return in_b + out_b + w_b
 
 
@@ -344,11 +351,13 @@ def _check_strategies(plan, budget: int, out: List[Diagnostic]) -> None:
                          "_strip_geometry's ceil-division"))
             (plo, phi), (qlo, qhi) = g.pads
             in_rows = (strat.strip_rows - 1) * g.stride + g.kernel
-            strip_bytes = in_rows * (g.w_in + qlo + qhi) * g.c_in * 4
+            # the kernel's double-buffered scratch: two strip (+halo) slots
+            strip_bytes = 2 * _vmem_tile_bytes(in_rows, g.w_in + qlo + qhi,
+                                               g.c_in)
             if strat.strip_rows > 1 and strip_bytes > budget:
                 out.append(Diagnostic(
                     "LTR022", "warning", step.name,
-                    f"one input strip (+halo) is "
+                    f"the double-buffered input strip (+halo) scratch is "
                     f"{strip_bytes / 2**20:.1f} MB, over the full "
                     f"{budget / 2**20:.1f} MB VMEM budget",
                     hint="shrink REPRO_CONV_VMEM_BUDGET-derived strips "

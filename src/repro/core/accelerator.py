@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from repro.core import optical_core as ocore
 from repro.core import power_model as pmod
-from repro.core.compressive import compressive_acquire
+from repro.core.compressive import compressive_acquire, window_pool
 from repro.core.quant import (WASpec, MixedPrecisionScheme, ACT_BITS,
                               quantize_weight, resolve_layer_specs)
 from repro.kernels import dispatch
@@ -271,9 +271,7 @@ class LightatorDevice:
                 y = _activation(y, layer.act)
                 if layer.pool is not None:
                     kind, size = layer.pool
-                    b_, h_, w_, c_ = y.shape
-                    yr = y.reshape(b_, h_ // size, size, w_ // size, size, c_)
-                    y = yr.max(axis=(2, 4)) if kind == "max" else yr.mean(axis=(2, 4))
+                    y = window_pool(y, kind, size)
                     if kind == "avg":
                         # avg pooling runs on CA banks with pre-set weights
                         schedules.append(ocore.schedule_ca(

@@ -18,6 +18,7 @@ Two realizations:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 RGB_COEFFS = (0.299, 0.587, 0.114)
@@ -37,6 +38,22 @@ def ca_coefficients(pool: int, channels: int = 3) -> jnp.ndarray:
     return w * chan[None, None, :]
 
 
+def window_pool(y: jnp.ndarray, kind: str, size: int) -> jnp.ndarray:
+    """``size`` x ``size`` max/avg pooling of [..., H, W, C] with a fixed
+    summation order: window taps added row-major, ``((y00 + y01) + y10) +
+    y11`` for size 2, then divided by ``size**2``. A reduce over the window
+    axes leaves the order to the compiler, which differs between backends
+    and between XLA and a Pallas kernel, and f32 addition is not
+    associative; the fused kernel adds its taps in this same order."""
+    out = None
+    for i in range(size):
+        for j in range(size):
+            t = y[..., i::size, j::size, :]
+            out = t if out is None else (
+                jnp.maximum(out, t) if kind == "max" else out + t)
+    return out if kind == "max" else out / (size * size)
+
+
 def compressive_acquire(img: jnp.ndarray, pool: int = 2,
                         rgb_to_gray: bool | None = None) -> jnp.ndarray:
     """Fused RGB->gray + pool x pool average pooling (single weighted MAC).
@@ -50,11 +67,13 @@ def compressive_acquire(img: jnp.ndarray, pool: int = 2,
         raise ValueError(f"H({h}), W({w}) must be divisible by pool={pool}")
     if rgb_to_gray is None:
         rgb_to_gray = (c == 3)
-    x = img.reshape(*lead, h // pool, pool, w // pool, pool, c)
     if rgb_to_gray:
+        x = img.reshape(*lead, h // pool, pool, w // pool, pool, c)
         coeffs = ca_coefficients(pool, c)            # [pool, pool, c]
-        return jnp.einsum("...hpwqc,pqc->...hw", x, coeffs)
-    return x.mean(axis=(-4, -2))
+        # HIGHEST: a TPU einsum otherwise rounds f32 operands to bf16
+        return jnp.einsum("...hpwqc,pqc->...hw", x, coeffs,
+                          precision=jax.lax.Precision.HIGHEST)
+    return window_pool(img, "avg", pool)
 
 
 def strided_conv_acquire(img: jnp.ndarray, weights: jnp.ndarray,

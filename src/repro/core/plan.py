@@ -22,7 +22,7 @@ layer IR, the [W:A] scheme, and the input shape. This module splits it out:
       the photonic MVM kernel and the CA through the fused ca_pool kernel;
       the reference backend uses the integer-exact jnp/lax oracles (convs
       stay ``conv_general_dilated`` — no patch materialization on large
-      frames). Because the OC accumulate is exact integer arithmetic on
+      frames; depthwise convs a tap loop). Because the OC accumulate is exact integer arithmetic on
       both backends, conv/dense routing cannot change the logits; with the
       dequant/activation/requant expressions kept textually identical to
       preserve float associativity, the compiled path is bit-identical to
@@ -84,7 +84,8 @@ def _nofma(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _crc_requant_traced(x: jnp.ndarray, a_qmax: jnp.ndarray,
-                        per_frame: bool = False):
+                        per_frame: bool = False,
+                        axis_name: Optional[str] = None):
     """`accelerator._crc_requant` with the divisor as a traced scalar.
 
     ``per_frame=False`` is the seed semantics: ONE scale from a max over the
@@ -95,6 +96,10 @@ def _crc_requant_traced(x: jnp.ndarray, a_qmax: jnp.ndarray,
     of batch composition, which is what lets the serving micro-batcher
     coalesce and pad requests without perturbing anyone's results. At
     batch 1 the two modes are the same reduction — bit-identical.
+
+    ``axis_name`` names the mesh axis the batch is split over when the
+    executor runs per shard (``shard_map``): the per-tensor max then also
+    reduces across shards (``pmax`` — exact, like any max).
     """
     x = jnp.maximum(x, 0.0)
     if per_frame:
@@ -102,6 +107,8 @@ def _crc_requant_traced(x: jnp.ndarray, a_qmax: jnp.ndarray,
         amax = jnp.max(x, axis=axes, keepdims=True)
     else:
         amax = jnp.max(x)
+        if axis_name is not None:
+            amax = jax.lax.pmax(amax, axis_name)
     scale = jnp.maximum(amax, 1e-8) / a_qmax
     codes = jnp.clip(jnp.round(x / scale), 0, (1 << ACT_BITS) - 1)
     return codes, scale
@@ -219,7 +226,8 @@ class CompiledPlan:
     # on first compile; "on" also re-checks cache hits — see _compile_model)
     _verified: bool = dataclasses.field(default=False, repr=False)
 
-    def executor(self, per_frame: bool = False, donate: bool = False):
+    def executor(self, per_frame: bool = False, donate: bool = False,
+                 mesh: Optional[jax.sharding.Mesh] = None):
         """The jitted (params, frames) -> logits function for this plan.
 
         Keyed by the active kernel backend AND the Pallas interpret flag:
@@ -238,16 +246,30 @@ class CompiledPlan:
         and never touches it again (a device-bound ``Executable`` stages
         its own input buffers, so it qualifies; the general ``run`` path
         must not, since callers may reuse what they passed).
+
+        ``mesh`` keys a fifth: the batch axis split over the mesh's first
+        axis, the steps run per shard under ``shard_map`` (Pallas kernels
+        cannot be partitioned by the compiler; each shard runs them on its
+        own frames) with the per-tensor calibration max reduced across
+        shards.
         """
         key = (dispatch.get_backend(), dispatch.default_interpret(), per_frame,
-               donate)
+               donate, mesh)
         fn = self._exec_fns.get(key)
         if fn is None:
-            fn = jax.jit(
-                lambda params, frames, consts: _execute_steps(
+            axis = mesh.axis_names[0] if mesh is not None else None
+
+            def run(params, frames, consts):
+                return _execute_steps(
                     self.steps, params, frames, consts, per_frame=per_frame,
-                    segments=self.fused_segments),
-                donate_argnums=(1,) if donate else ())
+                    segments=self.fused_segments, axis_name=axis)
+
+            if mesh is not None:
+                P = jax.sharding.PartitionSpec
+                run = jax.shard_map(run, mesh=mesh,
+                                    in_specs=(P(), P(axis), P()),
+                                    out_specs=P(axis), check_vma=False)
+            fn = jax.jit(run, donate_argnums=(1,) if donate else ())
             self._exec_fns[key] = fn
         return fn
 
@@ -561,8 +583,8 @@ def _compile_model_uncached(layers, frame_shape, scheme, oc, circuit,
 def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
                    frames: jnp.ndarray, consts: Dict[str, object],
                    per_frame: bool = False,
-                   segments: Tuple[dispatch.FusedSegmentSpec, ...] = ()
-                   ) -> jnp.ndarray:
+                   segments: Tuple[dispatch.FusedSegmentSpec, ...] = (),
+                   axis_name: Optional[str] = None) -> jnp.ndarray:
     """The device forward, batch-first, kernels via ``kernels.dispatch``.
 
     Numerics contract: bit-identical to ``LightatorDevice.run_eager`` (on
@@ -590,13 +612,22 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
     frames are calibration-independent — per-frame mode, or per-tensor at
     batch 1 (the batch is static under jit, so this is a trace-time
     fallback, not a runtime branch).
+
+    ``axis_name``: the steps run on one shard of a batch split over that
+    mesh axis (``CompiledPlan.executor(mesh=)``); per-tensor scales reduce
+    across shards, and a shard's batch of 1 is not the whole batch, so it
+    does not license per-tensor fusion.
     """
     from repro.core.accelerator import _activation
+    from repro.core.compressive import window_pool
 
     a_qmax = consts["a_qmax"]
-    codes, act_scale = _crc_requant_traced(frames, a_qmax, per_frame)
+    def requant(v):
+        return _crc_requant_traced(v, a_qmax, per_frame, axis_name)
+
+    codes, act_scale = requant(frames)
     x = codes
-    fuse_ok = per_frame or frames.shape[0] == 1
+    fuse_ok = per_frame or (frames.shape[0] == 1 and axis_name is None)
     if segments and not fuse_ok:
         # per-tensor calibration at batch > 1 couples frames through the
         # batch-wide CRC max: the fused segments cannot run, and this
@@ -634,7 +665,7 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
             g = dispatch.ca_acquire(intens, step.pool, step.rgb_to_gray)
             if g.ndim == 3:
                 g = g[..., None]
-            x, act_scale = _crc_requant_traced(g, a_qmax, per_frame)
+            x, act_scale = requant(g)
         elif isinstance(step, ConvStep):
             p = params[step.name]
             wq, ws = _quantize_weight_traced(p["w"], step.wa,
@@ -647,20 +678,17 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
                 out = _nofma(out) + p["b"]
             y = _activation(out, step.act)
             if step.pool is not None:
-                kind, size = step.pool
-                b_, h_, w_, c_ = y.shape
-                yr = y.reshape(b_, h_ // size, size, w_ // size, size, c_)
-                y = yr.max(axis=(2, 4)) if kind == "max" else yr.mean(axis=(2, 4))
-            x, act_scale = _crc_requant_traced(y, a_qmax, per_frame)
+                y = window_pool(y, *step.pool)
+            x, act_scale = requant(y)
         elif isinstance(step, UpsampleStep):
             from repro.core.compressive import upsample_reconstruct
             intens = x * act_scale
             up = upsample_reconstruct(intens, step.factor, step.method)
-            x, act_scale = _crc_requant_traced(up, a_qmax, per_frame)
+            x, act_scale = requant(up)
         elif isinstance(step, FlattenStep):
             intens = x * act_scale
             flat = intens.reshape(intens.shape[0], -1)
-            x, act_scale = _crc_requant_traced(flat, a_qmax, per_frame)
+            x, act_scale = requant(flat)
         elif isinstance(step, DenseStep):
             p = params[step.name]
             wq, ws = _quantize_weight_traced(p["w"], step.wa,
@@ -671,7 +699,7 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
                 out = _nofma(out) + p["b"]
             if step.act != "none":
                 y = _activation(out, step.act)
-                x, act_scale = _crc_requant_traced(y, a_qmax, per_frame)
+                x, act_scale = requant(y)
             else:
                 x, act_scale = out, jnp.asarray(1.0)
         else:
@@ -685,7 +713,8 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
 
 def _execute(plan: CompiledPlan, params: Dict[str, Dict],
              frames: jnp.ndarray, per_frame: bool = False,
-             donate: bool = False) -> jnp.ndarray:
+             donate: bool = False,
+             mesh: Optional[jax.sharding.Mesh] = None) -> jnp.ndarray:
     """Run ``frames`` [B, H, W, C] through a compiled plan.
 
     Returns logits [B, n] for classifier plans, or an image [B, H', W', C']
@@ -707,7 +736,8 @@ def _execute(plan: CompiledPlan, params: Dict[str, Dict],
         raise ValueError(f"frames {frames.shape} do not match plan frame "
                          f"shape {plan.frame_shape}; expected "
                          f"[B, {', '.join(map(str, plan.frame_shape))}]")
-    return plan.executor(per_frame, donate)(params, frames, plan.consts)
+    return plan.executor(per_frame, donate, mesh)(params, frames,
+                                                  plan.consts)
 
 
 # ---------------------------------------------------------------------------

@@ -452,11 +452,8 @@ class Executable:
         """
         frames = jnp.asarray(frames)
         with self._pinned():
-            if self._device is not None:
-                frames, params = self._place(frames)
-            else:
-                frames, params = self._shard(frames)
-            return plan_mod._execute(self._plan, params, frames)
+            frames, params, mesh = self._placed(frames)
+            return plan_mod._execute(self._plan, params, frames, mesh=mesh)
 
     def __call__(self, frames) -> jnp.ndarray:
         return self.run(frames)
@@ -514,12 +511,16 @@ class Executable:
         exe._staging_slots = int(staging_slots)
         return exe
 
-    def _place(self, frames: jnp.ndarray):
-        """Commit frames + (cached) params to the bound device."""
-        if self._device_params is None:
-            self._device_params = jax.device_put(self.program.params,
-                                                 self._device)
-        return jax.device_put(frames, self._device), self._device_params
+    def _placed(self, frames: jnp.ndarray):
+        """-> (frames, params, mesh) placed for this Executable: committed
+        to the bound device, or batch-sharded (mesh not None), or as is."""
+        if self._device is not None:
+            if self._device_params is None:
+                self._device_params = jax.device_put(self.program.params,
+                                                     self._device)
+            return (jax.device_put(frames, self._device),
+                    self._device_params, None)
+        return self._shard(frames)
 
     # -- serving: per-frame calibration + batch buckets -------------------
 
@@ -538,12 +539,10 @@ class Executable:
         """
         frames = jnp.asarray(frames)
         with self._pinned():
-            if self._device is not None:
-                frames, params = self._place(frames)
-            else:
-                frames, params = self._shard(frames)
+            frames, params, mesh = self._placed(frames)
             return plan_mod._execute(self._plan, params, frames,
-                                     per_frame=True, donate=self._donate)
+                                     per_frame=True, donate=self._donate,
+                                     mesh=mesh)
 
     def run_padded(self, frames, bucket: int) -> jnp.ndarray:
         """Padded-run helper: execute ``frames`` at a fixed batch bucket.
@@ -622,22 +621,23 @@ class Executable:
     # -- batch sharding ---------------------------------------------------
 
     def _shard(self, frames: jnp.ndarray):
-        """Shard the batch axis over local devices (ROADMAP item).
+        """Shard the batch axis over local devices -> (frames, params, mesh).
 
-        No-op unless ``options.shard_batch``, there are >= 2 devices, and
-        the batch divides the device count — the single-device laptop path
-        is byte-for-byte the unsharded one. Params are replicated (they are
-        small: filter taps / CNN weights), frames are split on axis 0; the
-        jitted executor picks the shardings up via GSPMD.
+        No-op (mesh None) unless ``options.shard_batch``, there are >= 2
+        devices, and the batch divides the device count — the
+        single-device laptop path is byte-for-byte the unsharded one.
+        Params are replicated (they are small: filter taps / CNN weights),
+        frames are split on axis 0, and the executor runs per shard under
+        ``shard_map`` (see ``CompiledPlan.executor``).
         """
         params = self.program.params
         if not self.options.shard_batch or frames.ndim != 4:
-            return frames, params
+            return frames, params, None
         if self._mesh is None:
             mesh = self.options.mesh
             if mesh is None:
                 if len(jax.local_devices()) <= 1:
-                    return frames, params
+                    return frames, params, None
                 mesh = jax.sharding.Mesh(
                     np.asarray(jax.local_devices()), ("batch",))
             self._mesh = mesh          # invariant for this Executable
@@ -647,11 +647,11 @@ class Executable:
         axis = mesh.axis_names[0]
         n = mesh.shape[axis]
         if n <= 1 or frames.shape[0] % n != 0:
-            return frames, params
+            return frames, params, None
         P = jax.sharding.PartitionSpec
         frames = jax.device_put(
             frames, jax.sharding.NamedSharding(mesh, P(axis)))
         if self._sharded_params is None:
             self._sharded_params = jax.device_put(
                 params, jax.sharding.NamedSharding(mesh, P()))
-        return frames, self._sharded_params
+        return frames, self._sharded_params, mesh

@@ -40,13 +40,12 @@ def bucket_leaves(tree: PyTree, bucket_bytes: int = 32 * 2**20) -> List[List]:
 
 def pod_psum(tree: PyTree, mesh, in_specs) -> PyTree:
     """Explicit psum over the 'pod' mesh axis via shard_map."""
-    from jax.experimental.shard_map import shard_map
 
     def f(x):
         return jax.tree.map(lambda v: jax.lax.psum(v, "pod"), x)
 
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=in_specs,
-                     check_rep=False)(tree)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=in_specs,
+                         check_vma=False)(tree)
 
 
 def with_optimization_barrier(x: PyTree) -> PyTree:

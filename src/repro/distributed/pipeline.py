@@ -97,14 +97,13 @@ def pipeline_forward(layer_params: PyTree, x: jnp.ndarray,
         mask = (sid == n_stages - 1).astype(acc.dtype)
         return jax.lax.psum(acc * mask, stage_axis)
 
-    from jax.experimental.shard_map import shard_map
     p_specs = jax.tree.map(
         lambda q: P(*((stage_axis,) + (None,) * (q.ndim - 1))), staged)
-    out = shard_map(
+    out = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(p_specs, P(*((None,) * xm.ndim))),
         out_specs=P(*((None,) * xm.ndim)),
-        check_rep=False)(staged, xm)
+        check_vma=False)(staged, xm)
     return out.reshape(x.shape)
 
 

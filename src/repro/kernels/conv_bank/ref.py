@@ -81,6 +81,7 @@ def conv_chain_ref(codes: jnp.ndarray, act_scale, stages, a_qmax):
     reduction as per-tensor calibration, bit for bit.
     """
     from repro.core.accelerator import _activation
+    from repro.core.compressive import window_pool
     x, scale = codes, act_scale
     for geom, wq, ws, bias in stages:
         acc = conv_taps_int(x, wq, geom.kernel, geom.stride, geom.pads,
@@ -91,10 +92,7 @@ def conv_chain_ref(codes: jnp.ndarray, act_scale, stages, a_qmax):
             out = jnp.nextafter(out, out) + bias
         y = _activation(out, geom.act)
         if geom.pool is not None:
-            kind, size = geom.pool
-            b_, h_, w_, c_ = y.shape
-            yr = y.reshape(b_, h_ // size, size, w_ // size, size, c_)
-            y = yr.max(axis=(2, 4)) if kind == "max" else yr.mean(axis=(2, 4))
+            y = window_pool(y, *geom.pool)
         y = jnp.maximum(y, 0.0)
         amax = jnp.max(y, axis=(1, 2, 3), keepdims=True)
         scale = jnp.maximum(amax, 1e-8) / a_qmax

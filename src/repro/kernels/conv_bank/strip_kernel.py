@@ -10,6 +10,12 @@ matrix) no longer fits on-chip. This module is the large-frame path:
     rows plus (k-1)-row halo are DMA'd into a VMEM scratch slot
     (``pltpu.make_async_copy``) — fetched once per strip and reused across
     every output-channel block;
+  * the channel (lane) axis is zero-padded to a multiple of 128 before the
+    kernel: the TPU lays VMEM out in (8, 128) tiles, and a DMA or slice of
+    a scratch whose lane extent is not tile-aligned is refused by the
+    compiler. Zero input channels (and zero weight rows) add exact zeros,
+    so the padding cannot change the accumulate. ``vmem_row_bytes`` is the
+    one place that prices a scratch row as the compiler lays it out;
   * the halo DMA is **double-buffered**: the scratch holds two strip slots
     with a DMA semaphore each, and while strip s's tap loop computes out of
     slot s%2, the DMA for strip s+1 is already in flight into the other
@@ -46,6 +52,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128          # TPU vreg lane count: last-dim tile of a VMEM buffer
+SUBLANES = 8         # f32 sublane count: second-to-last-dim tile
+
+
+def round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def vmem_row_bytes(width: int, channels: int) -> int:
+    """Bytes one [width, channels] f32 row occupies in VMEM: the compiler
+    pads the last dim to 128 lanes and the second-to-last to 8 sublanes."""
+    return round_up(width, SUBLANES) * round_up(channels, LANES) * 4
+
+
+def _pad_lanes(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Zero-pad ``axis`` up to a multiple of 128 lanes (no-op if aligned)."""
+    extra = round_up(x.shape[axis], LANES) - x.shape[axis]
+    if not extra:
+        return x
+    pads = [(0, 0)] * x.ndim
+    pads[axis] = (0, extra)
+    return jnp.pad(x, pads)
+
+
+def _pad_tiles(x: jnp.ndarray) -> jnp.ndarray:
+    """[B, Hp, Wp, C] -> zero columns up to a multiple of 8 sublanes and zero
+    channels up to a multiple of 128 lanes, so every DMA and slice of the
+    strip scratch is tile-aligned. Output width is computed from the real
+    ``Wp`` first; the extra columns are never read by a tap."""
+    extra = round_up(x.shape[2], SUBLANES) - x.shape[2]
+    if extra:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, extra), (0, 0)))
+    return _pad_lanes(x, 3)
+
+
+def _out_block(c_out: int, bn: int) -> int:
+    """Out-channel block: a multiple of 128 that divides ``c_out`` (at most
+    ``bn`` when that allows), else all of ``c_out`` — the only block widths
+    the TPU's (8, 128) block rule admits on the lane axis."""
+    if c_out % LANES:
+        return c_out
+    bn = max(LANES, bn - bn % LANES)
+    while c_out % bn:
+        bn -= LANES
+    return bn
+
 
 def pad_rows_for_strips(xp: jnp.ndarray, kk: int, stride: int,
                         strip_rows: int, n_strips: int) -> jnp.ndarray:
@@ -62,13 +114,17 @@ def pad_rows_for_strips(xp: jnp.ndarray, kk: int, stride: int,
     return jnp.pad(xp, ((0, 0), (0, extra), (0, 0), (0, 0)))
 
 
-def _tap_patch(x: jnp.ndarray, di: int, dj: int, strip_h: int, w_out: int,
-               stride: int, c: int) -> jnp.ndarray:
-    """The (di, dj) tap's strided window of a VMEM strip -> [strip_h, w_out, c]."""
-    return jax.lax.slice(
-        x, (di, dj, 0),
-        (di + (strip_h - 1) * stride + 1, dj + (w_out - 1) * stride + 1, c),
-        (stride, stride, 1))
+def _tap_patch(xs_ref, slot, di: int, dj: int, strip_h: int, w_out: int,
+               stride: int) -> jnp.ndarray:
+    """The (di, dj) tap's window of VMEM strip ``slot`` -> [strip_h, w_out, c].
+
+    A strided load from the scratch ref (rows are the major axis, columns
+    the sublane axis; the lane axis is never strided), not a strided slice
+    of a loaded value, which the TPU compiler does not lower."""
+    if stride == 1:
+        return xs_ref[slot, pl.ds(di, strip_h), pl.ds(dj, w_out), :]
+    return xs_ref[slot, pl.ds(di, strip_h, stride=stride),
+                  pl.ds(dj, w_out, stride=stride), :]
 
 
 def _epilogue(acc: jnp.ndarray, act_scale: float, ws, b, act: str):
@@ -123,6 +179,7 @@ def _conv_strip_kernel(x_hbm, w_ref, ws_ref, *rest, kk: int, stride: int,
     """One (strip, out-channel block) output tile.
 
     x_hbm:  [B, Hp, Wp, c_in] in ANY/HBM — never blocked into VMEM whole
+            (c_in lane-padded to a multiple of 128)
     w_ref:  [kk, kk, c_in, bn] VMEM        ws_ref: [1, bn]
     xs_ref: [2, rows_in, Wp, c_in] VMEM scratch (two strip+halo slots,
             double-buffered; persists across the innermost grid dim);
@@ -140,12 +197,12 @@ def _conv_strip_kernel(x_hbm, w_ref, ws_ref, *rest, kk: int, stride: int,
         _strip_dma(x_hbm, xs_ref, sems, b, s, stride=stride, strip_h=strip_h,
                    rows_in=rows_in, n_strips=n_strips)
 
-    x = xs_ref[jax.lax.rem(s, 2)]
+    slot = jax.lax.rem(s, 2)
     bn = out_ref.shape[-1]
     acc = jnp.zeros((strip_h * w_out, bn), jnp.float32)
     for di in range(kk):
         for dj in range(kk):
-            patch = _tap_patch(x, di, dj, strip_h, w_out, stride, c_in)
+            patch = _tap_patch(xs_ref, slot, di, dj, strip_h, w_out, stride)
             pf = patch.reshape(strip_h * w_out, c_in).astype(jnp.float32)
             wf = w_ref[di, dj].astype(jnp.float32)       # [c_in, bn]
             acc = acc + jax.lax.dot_general(
@@ -162,7 +219,7 @@ def _conv_strip_kernel(x_hbm, w_ref, ws_ref, *rest, kk: int, stride: int,
                                              "interpret"))
 def conv_strip_kernel(x_padded: jnp.ndarray, w: jnp.ndarray, ws: jnp.ndarray,
                       kk: int, stride: int = 1, strip_h: int = 8,
-                      bn: int = 64, act_scale: float = 1.0,
+                      bn: int = 128, act_scale: float = 1.0,
                       quantized: bool = False, act: str = "none",
                       bias: jnp.ndarray | None = None,
                       interpret: bool = True) -> jnp.ndarray:
@@ -175,8 +232,9 @@ def conv_strip_kernel(x_padded: jnp.ndarray, w: jnp.ndarray, ws: jnp.ndarray,
 
     On the quantized path ``act``/``bias`` fuse the per-layer epilogue
     (dequant -> bias -> activation) into the kernel — see ``_epilogue``.
+    ``bn`` bounds the out-channel block (see ``_out_block``).
     """
-    b, hp, wp, c_in = x_padded.shape
+    b, hp, wp, _ = x_padded.shape
     w_out = (wp - kk) // stride + 1
     n_rows = (hp - kk) // stride + 1
     if strip_h < 1:
@@ -189,14 +247,15 @@ def conv_strip_kernel(x_padded: jnp.ndarray, w: jnp.ndarray, ws: jnp.ndarray,
     n_strips = n_rows // strip_h
     rows_in = (strip_h - 1) * stride + kk
     c_out = w.shape[-1]
-    bn = min(bn, c_out)
-    while c_out % bn:
-        bn -= 1
+    bn = _out_block(c_out, bn)
+    x_padded = _pad_tiles(x_padded.astype(jnp.float32))
+    w = _pad_lanes(w.astype(jnp.float32), 2)
+    wp, c_in = x_padded.shape[2:]
     ws2 = ws.reshape(1, c_out).astype(jnp.float32)
     has_bias = bias is not None
-    operands = [x_padded.astype(jnp.float32), w.astype(jnp.float32), ws2]
+    operands = [x_padded, w, ws2]
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((kk, kk, c_in, bn), lambda i, s, n: (0, 0, 0, n)),
         pl.BlockSpec((1, bn), lambda i, s, n: (0, n)),
     ]
@@ -238,15 +297,14 @@ def _conv_strip_dw_kernel(x_hbm, w_ref, ws_ref, *rest, kk: int, stride: int,
     slot = _strip_dma(x_hbm, xs_ref, sems, b, s, stride=stride,
                       strip_h=strip_h, rows_in=rows_in, n_strips=n_strips)
 
-    x = xs_ref[slot]
     acc = jnp.zeros((strip_h, w_out, c), jnp.float32)
     for di in range(kk):
         for dj in range(kk):
-            patch = _tap_patch(x, di, dj, strip_h, w_out, stride, c)
-            acc = acc + patch.astype(jnp.float32) * w_ref[di * kk + dj]
+            patch = _tap_patch(xs_ref, slot, di, dj, strip_h, w_out, stride)
+            acc = acc + patch.astype(jnp.float32) * w_ref[pl.ds(di * kk + dj, 1), :]
     if quantized:
-        acc = _epilogue(acc, act_scale, ws_ref[0],
-                        b_ref[0] if has_bias else None, act)
+        acc = _epilogue(acc, act_scale, ws_ref[...],
+                        b_ref[...] if has_bias else None, act)
     out_ref[0] = acc.astype(out_ref.dtype)
 
 
@@ -261,9 +319,10 @@ def conv_strip_depthwise_kernel(x_padded: jnp.ndarray, w_taps: jnp.ndarray,
                                 interpret: bool = True) -> jnp.ndarray:
     """x_padded [B, Hp, Wp, C]; w_taps [kk*kk, C] -> [B, H_out, W_out, C].
 
-    Same row-padding contract as :func:`conv_strip_kernel`.
+    Same row-padding contract as :func:`conv_strip_kernel`; channels are
+    lane-padded inside and sliced back off the output.
     """
-    b, hp, wp, c = x_padded.shape
+    b, hp, wp, c_real = x_padded.shape
     w_out = (wp - kk) // stride + 1
     n_rows = (hp - kk) // stride + 1
     if strip_h < 1:
@@ -275,16 +334,19 @@ def conv_strip_depthwise_kernel(x_padded: jnp.ndarray, w_taps: jnp.ndarray,
             f"output rows, not a multiple of strip_h={strip_h}")
     n_strips = n_rows // strip_h
     rows_in = (strip_h - 1) * stride + kk
-    ws2 = ws.reshape(1, c).astype(jnp.float32)
+    x_padded = _pad_tiles(x_padded.astype(jnp.float32))
+    wp, c = x_padded.shape[2:]
+    ws2 = _pad_lanes(ws.reshape(1, c_real).astype(jnp.float32), 1)
     has_bias = bias is not None
-    operands = [x_padded.astype(jnp.float32), w_taps.astype(jnp.float32), ws2]
+    operands = [x_padded, _pad_lanes(w_taps.astype(jnp.float32), 1), ws2]
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec((kk * kk, c), lambda i, s: (0, 0)),
         pl.BlockSpec((1, c), lambda i, s: (0, 0)),
     ]
     if has_bias:
-        operands.append(jnp.asarray(bias, jnp.float32).reshape(1, c))
+        operands.append(_pad_lanes(
+            jnp.asarray(bias, jnp.float32).reshape(1, c_real), 1))
         in_specs.append(pl.BlockSpec((1, c), lambda i, s: (0, 0)))
     return pl.pallas_call(
         functools.partial(_conv_strip_dw_kernel, kk=kk, stride=stride,
@@ -299,4 +361,4 @@ def conv_strip_depthwise_kernel(x_padded: jnp.ndarray, w_taps: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((2, rows_in, wp, c), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
-    )(*operands)
+    )(*operands)[..., :c_real]

@@ -210,11 +210,14 @@ def conv_vmem_budget() -> int:
 
 def _strip_geometry(h_out: int, w_out: int, c_in: int, kernel: int,
                     stride: int, budget: int) -> ConvStrategy:
-    """Largest strip (output rows) whose input strip + halo fits budget/2."""
+    """Largest strip (output rows) whose double-buffered input strip + halo
+    scratch fits budget/2, priced as the TPU compiler lays VMEM out (lanes
+    padded to 128, sublanes to 8 — ``strip_kernel.vmem_row_bytes``)."""
+    from repro.kernels.conv_bank.strip_kernel import vmem_row_bytes
     wp = (w_out - 1) * stride + kernel        # padded input width
-    row_bytes = wp * c_in * 4                 # f32-carried codes
+    row_bytes = 2 * vmem_row_bytes(wp, c_in)  # two scratch slots, f32 codes
     # input rows needed for r output rows: (r-1)*stride + kernel
-    rows = (budget // 2 // max(row_bytes, 1) - kernel) // stride + 1
+    rows = (budget // 2 // row_bytes - kernel) // stride + 1
     rows = max(1, min(int(rows), h_out))
     if rows >= 8:
         rows -= rows % 8                      # f32 sublane-friendly strips
@@ -311,16 +314,18 @@ class ChainGeom:
         return h, w
 
     def stage_bytes(self) -> int:
-        """f32 working set of this stage inside the megakernel: padded
+        """f32 working set of this stage inside the megakernel, as the TPU
+        lays VMEM out (lanes padded to 128, sublanes to 8): zero-bordered
         input frame + output frame + weight block."""
+        from repro.kernels.conv_bank.strip_kernel import vmem_row_bytes
         (plo, phi), (qlo, qhi) = self.pads
-        in_b = (self.h_in + plo + phi) * (self.w_in + qlo + qhi) \
-            * self.c_in * 4
-        h_out = (self.h_in + plo + phi - self.kernel) // self.stride + 1
-        w_out = (self.w_in + qlo + qhi - self.kernel) // self.stride + 1
-        out_b = h_out * w_out * self.c_out * 4
-        w_b = self.kernel * self.kernel * (self.c_in // self.groups) \
-            * self.c_out * 4
+        hp, wp = self.h_in + plo + phi, self.w_in + qlo + qhi
+        in_b = hp * vmem_row_bytes(wp, self.c_in)
+        h_out = (hp - self.kernel) // self.stride + 1
+        w_out = (wp - self.kernel) // self.stride + 1
+        out_b = h_out * vmem_row_bytes(w_out, self.c_out)
+        w_b = self.kernel * self.kernel * vmem_row_bytes(
+            self.c_in // self.groups, self.c_out)
         return in_b + out_b + w_b
 
 
@@ -476,7 +481,11 @@ def conv_int(codes: jnp.ndarray, wq: jnp.ndarray, stride: int,
     VMEM-budget heuristic); ``core.plan`` passes the strategy it resolved
     and recorded at compile time. The reference backend ignores it —
     ``lax.conv_general_dilated`` on the float-carried codes is the exact op
-    the eager interpreter runs. Both pallas strategies accumulate the same
+    the eager interpreter runs; a depthwise conv runs as the tap loop of
+    ``conv_bank.ref.conv_taps_int`` instead, because XLA:TPU's grouped
+    convolution does not return the exact integer accumulate (on a v5e,
+    nearly every output of a 5x5 depthwise conv over 256² RGB codes was
+    off, by up to 1.4e3). Both pallas strategies accumulate the same
     exact integers, so strategy choice can never change the results.
     """
     k, _, cg, c_out = wq.shape
@@ -514,6 +523,9 @@ def conv_int(codes: jnp.ndarray, wq: jnp.ndarray, stride: int,
             outs.append(acc.reshape(b, h_out, w_out, og))
         return jnp.concatenate(outs, axis=-1)
     obs.counter("dispatch.conv.reference").inc()
+    if groups > 1 and cg == 1 and groups == c_out:
+        from repro.kernels.conv_bank.ref import conv_taps_int
+        return conv_taps_int(codes, wq, k, stride, pads, depthwise=True)
     return jax.lax.conv_general_dilated(
         codes.astype(jnp.float32), wq.astype(jnp.float32),
         window_strides=(stride, stride), padding=tuple(pads),
@@ -625,9 +637,10 @@ def ca_acquire(img: jnp.ndarray, pool: int,
     """Compressive Acquisitor dispatch. img [B, H, W, C].
 
     Returns [B, H', W'] (fused gray) or [B, H', W', C] (per-channel pooling),
-    matching ``core.compressive.compressive_acquire``. The Pallas kernel only
-    implements the fused single-output modes (rgb_to_gray or C == 1); the
-    per-channel multi-channel mode always uses the reference.
+    matching ``core.compressive.compressive_acquire``. On the pallas backend
+    every mode runs the ca_pool kernel; ``dispatch.ca.pallas`` /
+    ``dispatch.ca.reference`` count (at trace time, like the conv counters)
+    which backend each CA stage was traced for.
 
     NB: unlike matmul_int/conv_int this is *float* math — the kernel's tap
     summation order differs from the reference einsum by ~1 ulp, so the two
@@ -636,9 +649,12 @@ def ca_acquire(img: jnp.ndarray, pool: int,
     c = img.shape[-1]
     if rgb_to_gray is None:
         rgb_to_gray = (c == 3)
-    if get_backend() == "pallas" and (rgb_to_gray or c == 1):
+    if get_backend() == "pallas":
+        obs.counter("dispatch.ca.pallas").inc()
         from repro.kernels.ca_pool.ops import ca_pool
-        out = ca_pool(img, pool=pool, rgb_to_gray=rgb_to_gray)
-        return out if rgb_to_gray else out[..., None]
+        if rgb_to_gray:
+            return ca_pool(img, pool=pool, rgb_to_gray=True)
+        return ca_pool(img, pool=pool, per_channel=True)
+    obs.counter("dispatch.ca.reference").inc()
     from repro.core.compressive import compressive_acquire
     return compressive_acquire(img, pool, rgb_to_gray)

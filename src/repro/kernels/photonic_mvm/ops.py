@@ -2,7 +2,9 @@
 
 Handles: float->code quantization (CRC + MR imprinting), signed activations
 via the two-rail BPD trick (sign * |code|), block padding, leading dims,
-and the interpret switch (True on CPU — this container; False on real TPU).
+and the interpret switch (``dispatch.default_interpret``: True on CPU, False
+on a TPU, where the kernel compiles through Mosaic — ``tests/test_tpu_compile``
+compiles it for a described v5e, ``chip_smoke.py`` runs it on one).
 """
 
 from __future__ import annotations

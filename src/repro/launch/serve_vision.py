@@ -134,6 +134,8 @@ def main(argv=None):
     if args.load is not None and args.load <= 0:
         ap.error("--load must be > 0 requests/s")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     trace = None
     if args.trace is not None:
         from repro import obs
@@ -177,6 +179,13 @@ def main(argv=None):
           f"buckets={list(hosted.buckets)} wait={args.max_wait_ms}ms "
           f"devices={args.devices} compile={t_compile * 1e3:.1f}ms")
     print(f"[serve_vision] options: {options.describe()}")
+    resolved = options.resolve()
+    if jax.default_backend() == "tpu" and (resolved.backend != "pallas"
+                                           or resolved.interpret):
+        print(f"[serve_vision] WARNING: on a TPU but running "
+              f"backend={resolved.backend} interpret={resolved.interpret} "
+              f"(REPRO_KERNEL_BACKEND / REPRO_FORCE_INTERPRET / --backend)"
+              f" — not the compiled kernel path")
     if r.conv_strategy:
         # annotate each conv with its fused-segment membership: a conv
         # inside a segment executes in that segment's single launch, not

@@ -239,7 +239,6 @@ def _moe_block(params, x, slots, top_w, e, k, capacity, quant,
         return _combine_fallback(yb, slots, top_w, seq, k)
 
     mesh, rules = cur
-    from jax.experimental.shard_map import shard_map
     b_ax = rules.get("batch")
     b0 = (tuple(b_ax) if isinstance(b_ax, tuple) and len(b_ax) > 1
           else (b_ax[0] if isinstance(b_ax, tuple) else b_ax))
@@ -286,12 +285,12 @@ def _moe_block(params, x, slots, top_w, e, k, capacity, quant,
 
     w_spec = P(m_ax, d_ax, None)
     wd_spec = P(m_ax, None, d_ax)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(b0, None, None), P(b0, None), P(b0, None),
                   w_spec, w_spec, wd_spec),
         out_specs=P(b0, None, None),
-        check_rep=False)(x, slots, w_flat, wg_p, wu_p, wd_p)
+        check_vma=False)(x, slots, w_flat, wg_p, wu_p, wd_p)
     return out.astype(jnp.float32)
 
 

@@ -11,8 +11,8 @@ from repro.distributed.sharding import base_rules, spec_for, use_rules
 from repro.launch import shardings as sh
 from repro.launch import specs as specs_mod
 
-MESH_SINGLE = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH_SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MESH_MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", list_configs())

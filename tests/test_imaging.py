@@ -159,17 +159,18 @@ def test_pipelines_accept_grayscale_input(frames):
 
 # -- plan-runtime growth: depthwise conv + upsample step ---------------------
 
-def test_depthwise_conv_int_matches_manual():
+@pytest.mark.parametrize("stride", [1, 2])
+def test_depthwise_conv_int_matches_manual(stride):
     key = jax.random.PRNGKey(0)
     codes = jnp.round(jax.random.uniform(key, (2, 8, 8, 3)) * 15)
     wq = jnp.round(jax.random.uniform(jax.random.PRNGKey(1),
                                       (3, 3, 1, 3)) * 14) - 7
     pads = ((1, 1), (1, 1))
-    out = dispatch.conv_int(codes, wq, 1, pads, groups=3)
-    assert out.shape == (2, 8, 8, 3)
+    out = dispatch.conv_int(codes, wq, stride, pads, groups=3)
+    assert out.shape == (2, 8 // stride, 8 // stride, 3)
     for ch in range(3):
         ref = jax.lax.conv_general_dilated(
-            codes[..., ch:ch + 1], wq[..., ch:ch + 1], (1, 1), pads,
+            codes[..., ch:ch + 1], wq[..., ch:ch + 1], (stride, stride), pads,
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
         np.testing.assert_array_equal(np.asarray(out[..., ch]),
                                       np.asarray(ref[..., 0]))

@@ -298,6 +298,7 @@ def test_shard_batch_noop_on_single_device(lenet):
 
 
 _SHARD_SCRIPT = """
+import dataclasses
 import jax, numpy as np
 import repro
 from repro.core.quant import W4A4
@@ -318,6 +319,14 @@ mesh = jax.sharding.Mesh(np.asarray(jax.local_devices()), ("data",))
 out = prog.compile(repro.Options(scheme=W4A4, shard_batch=True,
                                  mesh=mesh)).run(frames)
 assert "data" in str(out.sharding), out.sharding
+np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+# Pallas kernels cannot be partitioned by the compiler: each shard runs
+# them on its own frames (shard_map), per-frame serving semantics
+opts = repro.Options(scheme=W4A4, backend="pallas", interpret=True)
+base = prog.compile(opts).run_per_frame(frames)
+out = prog.compile(dataclasses.replace(opts, shard_batch=True)) \
+    .run_per_frame(frames)
+assert len(out.sharding.device_set) == 4, out.sharding
 np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
 print("SHARD_OK")
 """
