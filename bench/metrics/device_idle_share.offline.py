@@ -1,0 +1,7 @@
+"""Share of the traced window in which the device ran no op, in %:
+1 - (union of device-op intervals / window), from the profiler trace."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    return None if tr is None else 100.0 * tr["idle_share"]
