@@ -260,6 +260,8 @@ class CompiledPlan:
             axis = mesh.axis_names[0] if mesh is not None else None
 
             def run(params, frames, consts):
+                # runs once per trace: each is a compile the runtime pays
+                obs.counter("plan.executor.traces").inc()
                 return _execute_steps(
                     self.steps, params, frames, consts, per_frame=per_frame,
                     segments=self.fused_segments, axis_name=axis)
@@ -580,6 +582,23 @@ def _compile_model_uncached(layers, frame_shape, scheme, oc, circuit,
 # Execute pass (pure, jitted once per plan)
 # ---------------------------------------------------------------------------
 
+def step_name(step: PlanStep) -> str:
+    """A plan step's stable name: the layer's own (``conv1_1``, ``fc8``)
+    or its kind (``ca``, ``upsample``, ``flatten``). The executor runs
+    each step under ``jax.named_scope`` of this name, so the compiled
+    program's ``op_name`` metadata and a device trace name the step."""
+    name = getattr(step, "name", None)
+    if name is not None:
+        return name
+    return {CAStep: "ca", UpsampleStep: "upsample",
+            FlattenStep: "flatten"}.get(type(step), type(step).__name__)
+
+
+def segment_name(seg: dispatch.FusedSegmentSpec) -> str:
+    """A fused segment's name: its steps' names joined by ``+``."""
+    return "+".join(seg.names)
+
+
 def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
                    frames: jnp.ndarray, consts: Dict[str, object],
                    per_frame: bool = False,
@@ -625,7 +644,8 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
     def requant(v):
         return _crc_requant_traced(v, a_qmax, per_frame, axis_name)
 
-    codes, act_scale = requant(frames)
+    with jax.named_scope("input"):
+        codes, act_scale = requant(frames)
     x = codes
     fuse_ok = per_frame or (frames.shape[0] == 1 and axis_name is None)
     if segments and not fuse_ok:
@@ -648,8 +668,9 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
         step = steps[i]
         seg = seg_at.get(i)
         if seg is not None:
-            with obs.span("plan.trace.fused_segment",
-                          attrs={"names": list(seg.names)}):
+            with jax.named_scope(segment_name(seg)), \
+                    obs.span("plan.trace.fused_segment",
+                             attrs={"names": list(seg.names)}):
                 stages = []
                 for s in steps[i:i + seg.length]:
                     p = params[s.name]
@@ -660,55 +681,57 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
                                                    a_qmax, per_frame)
             i += seg.length
             continue
-        if isinstance(step, CAStep):
-            intens = x * act_scale
-            g = dispatch.ca_acquire(intens, step.pool, step.rgb_to_gray)
-            if g.ndim == 3:
-                g = g[..., None]
-            x, act_scale = requant(g)
-        elif isinstance(step, ConvStep):
-            p = params[step.name]
-            wq, ws = _quantize_weight_traced(p["w"], step.wa,
-                                             consts["w_qmax"][step.name])
-            acc = dispatch.conv_int(x, wq, step.stride, step.pads,
-                                    groups=step.groups,
-                                    strategy=step.strategy)
-            out = acc * (act_scale * ws.reshape(1, 1, 1, -1))
-            if p.get("b") is not None:
-                out = _nofma(out) + p["b"]
-            y = _activation(out, step.act)
-            if step.pool is not None:
-                y = window_pool(y, *step.pool)
-            x, act_scale = requant(y)
-        elif isinstance(step, UpsampleStep):
-            from repro.core.compressive import upsample_reconstruct
-            intens = x * act_scale
-            up = upsample_reconstruct(intens, step.factor, step.method)
-            x, act_scale = requant(up)
-        elif isinstance(step, FlattenStep):
-            intens = x * act_scale
-            flat = intens.reshape(intens.shape[0], -1)
-            x, act_scale = requant(flat)
-        elif isinstance(step, DenseStep):
-            p = params[step.name]
-            wq, ws = _quantize_weight_traced(p["w"], step.wa,
-                                             consts["w_qmax"][step.name])
-            acc = dispatch.matmul_int(x, wq)
-            out = acc * (act_scale * ws.reshape(1, -1))
-            if p.get("b") is not None:
-                out = _nofma(out) + p["b"]
-            if step.act != "none":
+        with jax.named_scope(step_name(step)):
+            if isinstance(step, CAStep):
+                intens = x * act_scale
+                g = dispatch.ca_acquire(intens, step.pool, step.rgb_to_gray)
+                if g.ndim == 3:
+                    g = g[..., None]
+                x, act_scale = requant(g)
+            elif isinstance(step, ConvStep):
+                p = params[step.name]
+                wq, ws = _quantize_weight_traced(p["w"], step.wa,
+                                                 consts["w_qmax"][step.name])
+                acc = dispatch.conv_int(x, wq, step.stride, step.pads,
+                                        groups=step.groups,
+                                        strategy=step.strategy)
+                out = acc * (act_scale * ws.reshape(1, 1, 1, -1))
+                if p.get("b") is not None:
+                    out = _nofma(out) + p["b"]
                 y = _activation(out, step.act)
+                if step.pool is not None:
+                    y = window_pool(y, *step.pool)
                 x, act_scale = requant(y)
+            elif isinstance(step, UpsampleStep):
+                from repro.core.compressive import upsample_reconstruct
+                intens = x * act_scale
+                up = upsample_reconstruct(intens, step.factor, step.method)
+                x, act_scale = requant(up)
+            elif isinstance(step, FlattenStep):
+                intens = x * act_scale
+                flat = intens.reshape(intens.shape[0], -1)
+                x, act_scale = requant(flat)
+            elif isinstance(step, DenseStep):
+                p = params[step.name]
+                wq, ws = _quantize_weight_traced(p["w"], step.wa,
+                                                 consts["w_qmax"][step.name])
+                acc = dispatch.matmul_int(x, wq)
+                out = acc * (act_scale * ws.reshape(1, -1))
+                if p.get("b") is not None:
+                    out = _nofma(out) + p["b"]
+                if step.act != "none":
+                    y = _activation(out, step.act)
+                    x, act_scale = requant(y)
+                else:
+                    x, act_scale = out, jnp.asarray(1.0)
             else:
-                x, act_scale = out, jnp.asarray(1.0)
-        else:
-            raise TypeError(f"unknown plan step {step!r}")
+                raise TypeError(f"unknown plan step {step!r}")
         i += 1
     # dequantize the final stage (act_scale is 1.0 after a no-act dense, a
     # scalar per-tensor scale, or a [B, 1, ...] per-frame scale — all
     # broadcast-exact, and the per-tensor multiply is the seed expression)
-    return x * act_scale
+    with jax.named_scope("output"):
+        return x * act_scale
 
 
 def _execute(plan: CompiledPlan, params: Dict[str, Dict],

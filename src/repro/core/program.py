@@ -537,12 +537,72 @@ class Executable:
         either method. This is the executor ``repro.serve``'s micro-batcher
         coalesces requests onto.
         """
-        frames = jnp.asarray(frames)
+        return self.launch(self.place(frames))
+
+    def place(self, frames):
+        """Stage a batch for :meth:`launch`: ``jnp.asarray`` and the
+        placement's ``device_put`` -> (frames, params, mesh)."""
+        return self._placed(jnp.asarray(frames))
+
+    def launch(self, placed) -> jnp.ndarray:
+        """The per-frame-calibrated jitted call on a batch from
+        :meth:`place`; returns the lazy device result."""
+        frames, params, mesh = placed
         with self._pinned():
-            frames, params, mesh = self._placed(frames)
             return plan_mod._execute(self._plan, params, frames,
                                      per_frame=True, donate=self._donate,
                                      mesh=mesh)
+
+    def compiled_text(self, bucket: int) -> str:
+        """The compiled HLO text of the per-frame executor at batch
+        ``bucket``, as :meth:`launch` runs it (compiled again, from the
+        compile cache where there is one). Each instruction's ``op_name``
+        names the plan step it computes (``jax.named_scope`` in
+        ``core.plan``), so a profile's op names can be read by step."""
+        params = (self._device_params if self._device_params is not None
+                  else self.program.params)
+        spec = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
+        frames = jax.ShapeDtypeStruct((bucket, *self.program.input_hwc),
+                                      jnp.float32)
+        with self._pinned():
+            fn = self._plan.executor(True, self._donate, None)
+            return fn.lower(spec, frames,
+                            self._plan.consts).compile().as_text()
+
+    def pad_chunks(self, frames, bucket: int):
+        """Yield ``(chunk, real)``: ``frames`` zero-padded into
+        ``bucket``-sized chunks, each padded as it is asked for, ``real``
+        its count of real frames. See :meth:`run_padded`."""
+        if bucket < 1:
+            raise ValueError(f"bucket must be >= 1, got {bucket}")
+        frames = np.asarray(frames, np.float32)
+        if frames.ndim == 3:
+            frames = frames[None]
+        n = frames.shape[0]
+        for off in range(0, n, bucket):
+            chunk = frames[off:off + bucket]
+            real = chunk.shape[0]
+            if real < bucket:
+                if self._device is not None:
+                    key = (bucket, chunk.shape[1:])
+                    ring = self._staging.setdefault(key, [])
+                    if len(ring) < self._staging_slots:
+                        buf = np.zeros((bucket, *chunk.shape[1:]),
+                                       np.float32)
+                    else:
+                        # oldest slot: the batch that staged into it was
+                        # awaited >= slots-1 dispatches ago
+                        buf = ring.pop(0)
+                    ring.append(buf)
+                    buf[:real] = chunk
+                    buf[real:] = 0.0
+                    chunk = buf
+                else:
+                    chunk = np.concatenate(
+                        [chunk, np.zeros((bucket - real, *chunk.shape[1:]),
+                                         np.float32)])
+            yield chunk, real
 
     def run_padded(self, frames, bucket: int) -> jnp.ndarray:
         """Padded-run helper: execute ``frames`` at a fixed batch bucket.
@@ -570,37 +630,13 @@ class Executable:
         and pad content is provably inert either way (it cannot reach
         the real frames' results). Only the final chunk of an oversized
         batch can be partial, so one call uses at most one slot.
+
+        It is :meth:`pad_chunks`, then :meth:`place` and :meth:`launch` of
+        each chunk: the serving pool runs those steps itself to time
+        each one.
         """
-        if bucket < 1:
-            raise ValueError(f"bucket must be >= 1, got {bucket}")
-        frames = np.asarray(frames, np.float32)
-        if frames.ndim == 3:
-            frames = frames[None]
-        n = frames.shape[0]
-        outs = []
-        for off in range(0, n, bucket):
-            chunk = frames[off:off + bucket]
-            real = chunk.shape[0]
-            if real < bucket:
-                if self._device is not None:
-                    key = (bucket, chunk.shape[1:])
-                    ring = self._staging.setdefault(key, [])
-                    if len(ring) < self._staging_slots:
-                        buf = np.zeros((bucket, *chunk.shape[1:]),
-                                       np.float32)
-                    else:
-                        # oldest slot: the batch that staged into it was
-                        # awaited >= slots-1 dispatches ago
-                        buf = ring.pop(0)
-                    ring.append(buf)
-                    buf[:real] = chunk
-                    buf[real:] = 0.0
-                    chunk = buf
-                else:
-                    chunk = np.concatenate(
-                        [chunk, np.zeros((bucket - real, *chunk.shape[1:]),
-                                         np.float32)])
-            outs.append(self.run_per_frame(chunk)[:real])
+        outs = [self.launch(self.place(chunk))[:real]
+                for chunk, real in self.pad_chunks(frames, bucket)]
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
     def warm(self, buckets: Sequence[int] = (1,)) -> "Executable":

@@ -88,5 +88,6 @@ def ca_pool_kernel(img: jnp.ndarray, coeffs: jnp.ndarray, pool: int = 2,
                                lambda i, j: (i, 0, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, c_out, h_out, w_out), img.dtype),
         interpret=interpret,
+        name="ca_pool_kernel",
     )(planes, coeffs.astype(jnp.float32).reshape(taps))
     return out.transpose(0, 2, 3, 1) if per_channel else out[:, 0]

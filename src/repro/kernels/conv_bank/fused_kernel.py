@@ -223,5 +223,6 @@ def conv_chain_kernel(codes: jnp.ndarray, act_scale, stages: Sequence,
         ],
         scratch_shapes=scratch + pools,
         interpret=interpret,
+        name="conv_chain_kernel",
     )(*operands)
     return out, scale[:, :, :1].reshape(b, 1, 1, 1)

@@ -92,4 +92,5 @@ def conv_bank_kernel(x_padded: jnp.ndarray, w: jnp.ndarray, ws: jnp.ndarray,
                                lambda i, n: (i, 0, 0, n)),
         out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, c_out), jnp.float32),
         interpret=interpret,
+        name="conv_bank_kernel",
     )(*operands)

@@ -277,6 +277,7 @@ def conv_strip_kernel(x_padded: jnp.ndarray, w: jnp.ndarray, ws: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((2, rows_in, wp, c_in), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="conv_strip_kernel",
     )(*operands)
 
 
@@ -361,4 +362,5 @@ def conv_strip_depthwise_kernel(x_padded: jnp.ndarray, w_taps: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((2, rows_in, wp, c), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="conv_strip_depthwise_kernel",
     )(*operands)[..., :c_real]

@@ -90,4 +90,5 @@ def mvm_int_kernel(a_codes: jnp.ndarray, wq: jnp.ndarray, ws: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="mvm_int_kernel",
     )(a_codes, wq, ws2)

@@ -35,7 +35,8 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, RATIO_BUCKETS,
                                REGISTRY, Registry, counter, gauge, histogram)
 from repro.obs.trace import (TRACE_MODES, Trace, current_trace_id, disable,
                              enable, enabled, event, get_trace, now_ns,
-                             recording, span, span_at, trace_mode, use_mode)
+                             recording, set_thread_device, span, span_at,
+                             span_ns, trace_mode, use_mode)
 from repro.obs.flight import (FlightRecorder, get_flight, install,
                               install_default, uninstall)
 from repro.obs.log import StructuredLog
@@ -47,8 +48,8 @@ __all__ = [
     "TRACE_MODES", "Trace", "counter", "current_trace_id", "disable",
     "enable", "enabled", "event", "export_metrics", "gauge", "get_flight",
     "get_trace", "histogram", "install", "install_default", "now_ns",
-    "prometheus_text", "recording", "span", "span_at", "trace_mode",
-    "uninstall", "use_mode", "write_jsonl",
+    "prometheus_text", "recording", "set_thread_device", "span", "span_at",
+    "span_ns", "trace_mode", "uninstall", "use_mode", "write_jsonl",
 ]
 
 # the always-on black box: installed unless REPRO_FLIGHT=off

@@ -1,41 +1,55 @@
-"""Always-on flight recorder: fixed-size per-thread span/event rings.
+"""Always-on flight recorder: per-thread rings of fixed-width integer records.
 
-The PR-7 tracer is export-on-demand: a timeline exists only if the
-operator installed a collector *before* the anomaly. Production
-incidents do not announce themselves, so this module keeps the last N
-records per thread in a preallocated ring buffer that records **even
-when tracing is off** — then :meth:`FlightRecorder.dump` reconstructs
-the final seconds before any trigger (SLO breach, ``WorkerError``,
-stop-timeout stranding) as the same Chrome-trace JSON
-``scripts/check_trace.py`` already validates.
+The ``Trace`` collector is export-on-demand: a timeline exists only if the
+operator installed a collector *before* the anomaly. Production incidents
+do not announce themselves, so this module keeps the last N records per
+thread in a preallocated ring that records **even when tracing is off**;
+:meth:`FlightRecorder.dump` reconstructs the final seconds before any
+trigger (SLO breach, ``WorkerError``, stop-timeout stranding) as the same
+Chrome-trace JSON ``scripts/check_trace.py --flight`` validates.
 
-Design constraints, in order:
+A record is ``WIDTH`` signed 64-bit integers in one flat buffer per ring::
 
-* **No allocation on the hot path.** Every ring slot is a fixed-shape
-  list preallocated at ring creation; ``put`` mutates the slot fields in
-  place under a per-ring lock. Recording a span touches one lock, nine
-  list stores and two integer adds — measured well under the 5%
-  serving-load budget gated by ``BENCH_obs.json`` (``flight`` section).
-* **Overwrite-oldest.** The ring wraps; a monotonically increasing
-  per-ring ``seq`` stamps every record so a dump can prove the retained
-  history is gap-free (``check_trace.py --flight`` checks seq
-  contiguity per ring).
-* **Per-thread rings.** One ring per recording OS thread — no
-  cross-thread contention on the hot path. Rings are registered by
-  thread id; a thread-local caches the calling thread's ring so the
-  registry lock is only taken on first use per thread.
+    seq, ph, name, t0_ns, t1_ns, lane_tid, lane, trace_id, n_attrs,
+    (key, value) x MAX_ATTRS
+
+* ``ph`` is 0 for a span ("X"), 1 for an instant ("i");
+* ``name``, ``lane`` and ``trace_id`` are string codes from the
+  recorder's intern table (0: none). A string that ends in ``-<digits>``
+  (``vgg9/req-41``) is coded as its interned prefix and the number, so
+  per-request ids do not grow the table;
+* ``lane_tid`` is the synthetic display lane of a retrospective span
+  (0: the recording thread's own lane);
+* each attribute is a key, ``index << 3 | tag`` of the interned key name,
+  and a value: an int, a string code, a float's bits, a bool, None, or
+  the code of a JSON text for anything else (lists). Up to ``MAX_ATTRS``
+  are kept; a dump marks a record that had more.
+
+Recording stores integers into the ring under a per-ring lock: it keeps
+no reference to the caller's objects and creates no object the garbage
+collector tracks, so the always-on recorder neither grows the heap nor
+brings on collections. The ring overwrites its oldest record; the
+per-ring ``seq`` stamps every record, so a dump (``check_trace.py
+--flight``) or a reader of :meth:`FlightRecorder.rows` can prove the
+history it holds has no gap.
 
 Installation is process-global (``install()`` / ``uninstall()``), and
 ``repro.obs`` installs a default recorder at import time unless
-``REPRO_FLIGHT=off`` (capacity via ``REPRO_FLIGHT_SLOTS``, default
-2048 slots/thread). ``obs.span``/``obs.event``/``obs.span_at`` feed the
-recorder from ``trace.py`` whenever one is installed, independent of
-the ``Options(trace=)`` tri-state.
+``REPRO_FLIGHT=off`` (capacity via ``REPRO_FLIGHT_SLOTS``, default 2048
+records per thread). ``obs.span``/``obs.event``/``obs.span_at``/
+``obs.span_ns`` feed the recorder from ``trace.py`` whenever one is
+installed, independent of the ``Options(trace=)`` tri-state (``span_ns``,
+the serving path's batch spans, feeds it alone).
 """
 
 from __future__ import annotations
 
+import array
+import json
+import mmap
+import numbers
 import os
+import struct
 import threading
 from typing import Dict, List, Optional
 
@@ -44,59 +58,147 @@ from repro.obs.trace import _TID_META_PID, now_ns
 
 DEFAULT_CAPACITY = 2048
 
-# slot field indices (a slot is a fixed 9-element list, mutated in place)
-_SEQ, _PH, _NAME, _T0, _T1, _TRACE_ID, _ATTRS, _LANE_TID, _LANE = range(9)
+# record layout (fields of one record, in order)
+SEQ, PH, NAME, T0, T1, LANE_TID, LANE, TRACE, N_ATTRS = range(9)
+ATTR0 = 9
+MAX_ATTRS = 6
+WIDTH = ATTR0 + 2 * MAX_ATTRS
+
+_RECORD_BYTES = WIDTH * 8
+_HEADER = struct.Struct(f"={ATTR0}q")
+_THREE_ATTRS = struct.Struct("=6q")
+
+PH_SPAN, PH_INSTANT = 0, 1
+_PH_NAMES = ("X", "i")
+
+# attribute value tags (the low 3 bits of an attribute key)
+T_INT, T_STR, T_FLOAT, T_BOOL, T_NONE, T_JSON = range(1, 7)
+
+# a string code: the interned string's index above SUFFIX_BITS, and a
+# numeric suffix + 1 below them (0: none)
+SUFFIX_BITS = 40
+_SUFFIX_MASK = (1 << SUFFIX_BITS) - 1
+_MAX_SUFFIX_DIGITS = 12             # < 2**40
+MAX_STRINGS = 1 << 16
+_OVERFLOW = 1                       # index of the "string table full" mark
 
 
 class _Ring:
     """One thread's preallocated record ring.
 
-    ``slots`` is a list of ``capacity`` fixed-shape lists; ``head`` is
-    the next slot to (over)write and ``seq`` the total records ever
-    written — so ``seq - capacity`` is the oldest retained sequence
-    number once the ring has wrapped.
+    ``q`` is ``capacity * WIDTH`` int64 fields in an anonymous mapping
+    (pages are touched only as records reach them); ``head`` is the next
+    record to (over)write and ``seq`` the total records ever written — so
+    the ring holds ``seq - min(seq, capacity)`` onwards.
     """
 
-    __slots__ = ("tid", "lane", "slots", "head", "seq", "lock")
+    __slots__ = ("tid", "lane", "capacity", "mem", "q", "head", "seq",
+                 "lock", "_fd", "_fq")
 
     def __init__(self, tid: int, lane: str, capacity: int):
         self.tid = tid
         self.lane = lane
-        self.slots: List[list] = [
-            [0, "", "", 0, 0, None, None, None, None]
-            for _ in range(capacity)]
+        self.capacity = capacity
+        self.mem = mmap.mmap(-1, capacity * WIDTH * 8)
+        self.q = memoryview(self.mem).cast("q")
         self.head = 0
         self.seq = 0
         self.lock = threading.Lock()
+        # a float's bits, read through a second view of one 8-byte cell
+        cell = bytearray(8)
+        self._fd = memoryview(cell).cast("d")
+        self._fq = memoryview(cell).cast("q")
 
-    def put(self, ph: str, name: str, t0_ns: int, t1_ns: int,
-            trace_id: Optional[str], attrs: Optional[Dict],
-            lane_tid: Optional[int], lane: Optional[str]) -> None:
-        """Overwrite the oldest slot with one record. No allocation."""
+    def put(self, ph: int, name: int, t0_ns: int, t1_ns: int,
+            lane_tid: int, lane: int, trace: int, n: int,
+            k0: int = 0, v0: int = 0, k1: int = 0, v1: int = 0,
+            k2: int = 0, v2: int = 0) -> None:
+        """Overwrite the oldest record with a record of up to three
+        already-encoded attributes."""
         with self.lock:
-            slot = self.slots[self.head]
-            slot[_SEQ] = self.seq
-            slot[_PH] = ph
-            slot[_NAME] = name
-            slot[_T0] = t0_ns
-            slot[_T1] = t1_ns
-            slot[_TRACE_ID] = trace_id
-            slot[_ATTRS] = attrs
-            slot[_LANE_TID] = lane_tid
-            slot[_LANE] = lane
-            self.head = (self.head + 1) % len(self.slots)
+            off = self.head * _RECORD_BYTES
+            _HEADER.pack_into(self.mem, off, self.seq, ph, name, t0_ns, t1_ns,
+                              lane_tid, lane, trace, n)
+            if n:
+                _THREE_ATTRS.pack_into(self.mem, off + _HEADER.size, k0, v0,
+                                       k1, v1, k2, v2)
+            self.head = self.head + 1 if self.head + 1 < self.capacity else 0
             self.seq = self.seq + 1
 
-    def snapshot(self) -> List[list]:
-        """Retained records oldest -> newest (copies; safe post-return)."""
+    def put_attrs(self, rec: "FlightRecorder", ph: int, name: int,
+                  t0_ns: int, t1_ns: int, lane_tid: int, lane: int,
+                  trace: int, attrs: Dict) -> None:
+        """Overwrite the oldest record, encoding a caller's ``attrs``
+        dict into the record's attribute fields."""
+        q = self.q
+        index = rec._index
         with self.lock:
-            n = len(self.slots)
-            count = min(self.seq, n)
-            start = (self.head - count) % n
-            out = []
-            for i in range(count):
-                out.append(list(self.slots[(start + i) % n]))
-            return out
+            off = self.head * _RECORD_BYTES
+            n = len(attrs)
+            _HEADER.pack_into(self.mem, off, self.seq, ph, name, t0_ns, t1_ns,
+                              lane_tid, lane, trace,
+                              n if n <= MAX_ATTRS else MAX_ATTRS + 1)
+            a = self.head * WIDTH + ATTR0
+            end = a + 2 * MAX_ATTRS
+            for key in attrs:
+                if a == end:
+                    break
+                value = attrs[key]
+                k = index.get(key)
+                k = (rec.key(key) if k is None else k) << 3
+                t = type(value)
+                if t is int and -(1 << 63) <= value < (1 << 63):
+                    q[a] = k | T_INT
+                    q[a + 1] = value
+                elif t is str:
+                    q[a] = k | T_STR
+                    q[a + 1] = rec.code(value)
+                elif t is float:
+                    self._fd[0] = value
+                    q[a] = k | T_FLOAT
+                    q[a + 1] = self._fq[0]
+                elif t is bool:
+                    q[a] = k | T_BOOL
+                    q[a + 1] = 1 if value else 0
+                elif value is None:
+                    q[a] = k | T_NONE
+                    q[a + 1] = 0
+                elif (isinstance(value, numbers.Integral)
+                      and -(1 << 63) <= int(value) < (1 << 63)):
+                    q[a] = k | T_INT
+                    q[a + 1] = int(value)
+                elif isinstance(value, numbers.Real):
+                    self._fd[0] = float(value)
+                    q[a] = k | T_FLOAT
+                    q[a + 1] = self._fq[0]
+                else:
+                    q[a] = k | T_JSON
+                    q[a + 1] = rec.code(json.dumps(value, default=str))
+                a += 2
+            self.head = self.head + 1 if self.head + 1 < self.capacity else 0
+            self.seq = self.seq + 1
+
+    def rows(self, since: int = 0) -> tuple:
+        """``(first_seq, rows)``: the held records with ``seq >= since``,
+        oldest first, as a flat ``array('q')`` copy of ``WIDTH`` fields
+        each; ``first_seq`` is the oldest held record's seq (above
+        ``since`` when the ring overwrote part of what was asked for)."""
+        with self.lock:
+            held = min(self.seq, self.capacity)
+            first = self.seq - held
+            start = max(first, since)
+            count = self.seq - start
+            out = array.array("q")
+            if count > 0:
+                i = (self.head - count) % self.capacity
+                j = i + count
+                if j <= self.capacity:
+                    out.frombytes(self.q[i * WIDTH:j * WIDTH].tobytes())
+                else:
+                    out.frombytes(self.q[i * WIDTH:].tobytes())
+                    out.frombytes(
+                        self.q[:(j - self.capacity) * WIDTH].tobytes())
+            return first, out
 
 
 class FlightRecorder:
@@ -112,6 +214,81 @@ class FlightRecorder:
         self._rings: Dict[int, _Ring] = {}
         self._tls = threading.local()
         self._dumps = 0
+        # the intern table: index -> string, and string -> index
+        self._strings: List[Optional[str]] = [None, "?"]
+        self._index: Dict[str, int] = {}
+        self._intern_lock = threading.Lock()
+        # the keys of record_fields, tagged as integers
+        self._fields = tuple(self.key(k) << 3 | T_INT
+                             for k in ("device", "bucket", "frames"))
+
+    # -- the intern table ----------------------------------------------------
+
+    def _intern(self, s: str) -> int:
+        i = self._index.get(s)
+        if i is not None:
+            return i
+        with self._intern_lock:
+            i = self._index.get(s)
+            if i is None:
+                if len(self._strings) >= MAX_STRINGS:
+                    return _OVERFLOW
+                i = len(self._strings)
+                self._strings.append(s)
+                self._index[s] = i
+            return i
+
+    def key(self, s: str) -> int:
+        """The intern index of an attribute key (no suffix split)."""
+        return self._intern(s)
+
+    def code(self, s: Optional[str]) -> int:
+        """The integer code of ``s`` (0 for None)."""
+        if s is None:
+            return 0
+        i = self._index.get(s)
+        if i is not None:
+            return i << SUFFIX_BITS
+        head, sep, tail = s.rpartition("-")
+        if (sep and tail.isascii() and tail.isdigit()
+                and len(tail) <= _MAX_SUFFIX_DIGITS
+                and (tail[0] != "0" or len(tail) == 1)):
+            return ((self._intern(head + sep) << SUFFIX_BITS)
+                    | (int(tail) + 1))
+        return self._intern(s) << SUFFIX_BITS
+
+    def decode(self, code: int) -> Optional[str]:
+        """The string of a code (None for 0)."""
+        if code == 0:
+            return None
+        s = self._strings[code >> SUFFIX_BITS]
+        n = code & _SUFFIX_MASK
+        return s if n == 0 else f"{s}{n - 1}"
+
+    def _value(self, key: int, value: int):
+        tag = key & 7
+        if tag == T_INT:
+            return value
+        if tag == T_STR:
+            return self.decode(value)
+        if tag == T_FLOAT:
+            return array.array("d", array.array("q", [value]).tobytes())[0]
+        if tag == T_BOOL:
+            return bool(value)
+        if tag == T_JSON:
+            return json.loads(self.decode(value))
+        return None
+
+    def attrs(self, row) -> Dict:
+        """A record's attributes (``row``: its ``WIDTH`` fields)."""
+        out = {}
+        n = row[N_ATTRS]
+        for j in range(min(n, MAX_ATTRS)):
+            k = row[ATTR0 + 2 * j]
+            out[self._strings[k >> 3]] = self._value(k, row[ATTR0 + 2 * j + 1])
+        if n > MAX_ATTRS:
+            out["attrs_truncated"] = True
+        return out
 
     # -- recording (hot path) ----------------------------------------------
 
@@ -135,15 +312,83 @@ class FlightRecorder:
                     attrs: Optional[Dict] = None,
                     lane_tid: Optional[int] = None,
                     lane: Optional[str] = None) -> None:
-        self._ring().put("X", name, t0_ns, t1_ns, trace_id, attrs,
-                         lane_tid, lane)
+        trace = self.code(trace_id)
+        # a request's lane is named by its trace id: code it once
+        lane_code = trace if lane is trace_id else self.code(lane)
+        if attrs:
+            self._ring().put_attrs(self, PH_SPAN, self.code(name), t0_ns,
+                                   t1_ns, lane_tid or 0, lane_code, trace,
+                                   attrs)
+        else:
+            self._ring().put(PH_SPAN, self.code(name), t0_ns, t1_ns,
+                             lane_tid or 0, lane_code, trace, 0)
 
     def record_event(self, name: str, t_ns: Optional[int] = None,
                      trace_id: Optional[str] = None,
                      attrs: Optional[Dict] = None) -> None:
         if t_ns is None:
             t_ns = now_ns()
-        self._ring().put("i", name, t_ns, t_ns, trace_id, attrs, None, None)
+        if attrs:
+            self._ring().put_attrs(self, PH_INSTANT, self.code(name), t_ns,
+                                   t_ns, 0, 0, self.code(trace_id), attrs)
+        else:
+            self._ring().put(PH_INSTANT, self.code(name), t_ns, t_ns, 0, 0,
+                             self.code(trace_id), 0)
+
+    def record_fields(self, name: str, t0_ns: int, t1_ns: int,
+                      device: int = -1, bucket: int = -1,
+                      frames: int = -1) -> None:
+        """A span whose attributes are the serving path's integer fields;
+        a negative field is left out. Nothing is allocated: the field
+        keys were interned when the recorder was made."""
+        kd, kb, kf = self._fields
+        if device >= 0 and bucket >= 0 and frames >= 0:
+            self._ring().put(PH_SPAN, self.code(name), t0_ns, t1_ns, 0, 0, 0,
+                             3, kd, device, kb, bucket, kf, frames)
+            return
+        # pack the present fields to the front
+        n = 0
+        k0 = v0 = k1 = v1 = k2 = v2 = 0
+        for k, v in ((kd, device), (kb, bucket), (kf, frames)):
+            if v < 0:
+                continue
+            if n == 0:
+                k0, v0 = k, v
+            elif n == 1:
+                k1, v1 = k, v
+            else:
+                k2, v2 = k, v
+            n += 1
+        self._ring().put(PH_SPAN, self.code(name), t0_ns, t1_ns, 0, 0, 0, n,
+                         k0, v0, k1, v1, k2, v2)
+
+    # -- reading -------------------------------------------------------------
+
+    def seqs(self) -> Dict[int, int]:
+        """Each ring's total records written so far, by ring (thread) id:
+        a reader's mark of where a part of the run begins."""
+        with self._lock:
+            rings = list(self._rings.values())
+        return {r.tid: r.seq for r in rings}
+
+    def rows(self, since: Optional[Dict[int, int]] = None) -> List[Dict]:
+        """Every ring's held records, as raw integer rows: a list of
+        ``{"tid", "lane", "seq", "first_seq", "since", "wrapped",
+        "rows"}`` with ``rows`` an ``array('q')`` of ``WIDTH`` fields a
+        record, oldest first. With ``since`` (a :meth:`seqs` mark), only
+        the records written after it, and ``wrapped`` says whether the
+        ring overwrote any of them (a ring made after the mark counts
+        from 0)."""
+        with self._lock:
+            rings = list(self._rings.values())
+        out = []
+        for r in rings:
+            start = (since or {}).get(r.tid, 0) if since is not None else 0
+            first, rows = r.rows(start)
+            out.append({"tid": r.tid, "lane": r.lane, "seq": r.seq,
+                        "first_seq": first, "since": start,
+                        "wrapped": first > start, "rows": rows})
+        return out
 
     # -- dumping -----------------------------------------------------------
 
@@ -158,15 +403,15 @@ class FlightRecorder:
         retained history is gap-free.
         """
         with self._lock:
-            rings = list(self._rings.values())
             self._dumps = self._dumps + 1
-        ring_snaps = [(r, r.snapshot()) for r in rings]
+        ring_rows = self.rows()
 
         epoch = None
-        for _, snap in ring_snaps:
-            for rec in snap:
-                if epoch is None or rec[_T0] < epoch:
-                    epoch = rec[_T0]
+        for rr in ring_rows:
+            rows = rr["rows"]
+            for b in range(0, len(rows), WIDTH):
+                if epoch is None or rows[b + T0] < epoch:
+                    epoch = rows[b + T0]
         if epoch is None:
             epoch = now_ns()
 
@@ -174,27 +419,32 @@ class FlightRecorder:
         lanes: Dict[int, str] = {}
         total = 0
         dropped = 0
-        for ring, snap in ring_snaps:
-            total += len(snap)
-            dropped += max(0, ring.seq - len(snap))
-            lanes.setdefault(ring.tid, f"flight:{ring.lane}")
-            for rec in snap:
-                tid = ring.tid
-                if rec[_LANE_TID] is not None:
-                    tid = rec[_LANE_TID]
-                    if rec[_LANE] is not None:
-                        lanes.setdefault(tid, rec[_LANE])
-                args = dict(rec[_ATTRS]) if rec[_ATTRS] else {}
-                args["seq"] = rec[_SEQ]
-                args["ring"] = ring.tid
-                if rec[_TRACE_ID] is not None:
-                    args["trace_id"] = rec[_TRACE_ID]
-                ev = {"name": rec[_NAME], "ph": rec[_PH],
-                      "cat": rec[_NAME].split(".", 1)[0],
+        for rr in ring_rows:
+            rows = rr["rows"]
+            held = len(rows) // WIDTH
+            total += held
+            dropped += max(0, rr["seq"] - held)
+            lanes.setdefault(rr["tid"], f"flight:{rr['lane']}")
+            for b in range(0, len(rows), WIDTH):
+                row = rows[b:b + WIDTH]
+                tid = rr["tid"]
+                if row[LANE_TID]:
+                    tid = row[LANE_TID]
+                    if row[LANE]:
+                        lanes.setdefault(tid, self.decode(row[LANE]))
+                args = self.attrs(row)
+                args["seq"] = row[SEQ]
+                args["ring"] = rr["tid"]
+                if row[TRACE]:
+                    args["trace_id"] = self.decode(row[TRACE])
+                name = self.decode(row[NAME])
+                ph = _PH_NAMES[row[PH]]
+                ev = {"name": name, "ph": ph,
+                      "cat": name.split(".", 1)[0],
                       "pid": _TID_META_PID, "tid": tid,
-                      "ts": (rec[_T0] - epoch) / 1e3, "args": args}
-                if rec[_PH] == "X":
-                    ev["dur"] = (rec[_T1] - rec[_T0]) / 1e3
+                      "ts": (row[T0] - epoch) / 1e3, "args": args}
+                if ph == "X":
+                    ev["dur"] = (row[T1] - row[T0]) / 1e3
                 else:
                     ev["s"] = "t"
                 events.append(ev)
@@ -206,9 +456,10 @@ class FlightRecorder:
                 "otherData": {"flight": self.name,
                               "reason": reason,
                               "capacity": self.capacity,
-                              "rings": len(ring_snaps),
+                              "rings": len(ring_rows),
                               "records": total,
-                              "dropped_total": dropped}}
+                              "dropped_total": dropped,
+                              "strings": len(self._strings)}}
 
     def stats(self) -> Dict:
         with self._lock:
@@ -218,7 +469,8 @@ class FlightRecorder:
         total = sum(r.seq for r in rings)
         return {"rings": len(rings), "capacity": self.capacity,
                 "retained": retained, "recorded_total": total,
-                "dropped_total": total - retained, "dumps": dumps}
+                "dropped_total": total - retained, "dumps": dumps,
+                "strings": len(self._strings)}
 
 
 # ---------------------------------------------------------------------------

@@ -187,7 +187,20 @@ class Trace:
 
 _active: Optional[Trace] = None
 _active_lock = threading.Lock()
-_tls = threading.local()           # .stack (open spans), .mode, .trace_id
+
+
+class _ThreadState(threading.local):
+    """Per-thread recording state. Class-level defaults make a read of an
+    unset field a plain lookup, not a caught ``AttributeError``: the
+    hot path reads ``mode`` on every record."""
+
+    mode: Optional[str] = None        # use_mode() pin
+    stack: Optional[list] = None      # open spans
+    trace_id: Optional[str] = None    # inherited from an enclosing span
+    device: int = -1                  # set_thread_device() pin
+
+
+_tls = _ThreadState()
 
 # The process flight recorder (obs.flight.FlightRecorder), if installed.
 # Managed by obs.flight.install/uninstall; read here on the hot path so
@@ -437,3 +450,32 @@ def span_at(name: str, t0_s: float, t1_s: float,
     if flight is not None:
         flight.record_span(name, t0_ns, t1_ns, trace_id=trace_id,
                            attrs=attrs, lane_tid=lane_tid, lane=lane)
+
+
+def set_thread_device(device: int) -> None:
+    """Make ``device`` the default ``device`` field of the calling
+    thread's :func:`span_ns` records: a pool worker's thread is that
+    device's lane."""
+    _tls.device = int(device)
+
+
+def span_ns(name: str, t0_ns: int, t1_ns: int, device: int = -1,
+            bucket: int = -1, frames: int = -1) -> None:
+    """Record a finished span from ``now_ns()`` stamps, with integer
+    fields only: a negative field is left out, and ``device`` defaults to
+    the thread's :func:`set_thread_device`. The serving path's per-batch
+    spans use it. The flight ring takes it without allocating; an enabled
+    :class:`Trace` collector gets it too, its fields as ``attrs``."""
+    flight = _flight
+    to_trace = enabled()
+    if flight is None and not to_trace:
+        return
+    if device < 0:
+        device = _tls.device
+    if flight is not None:
+        flight.record_fields(name, t0_ns, t1_ns, device, bucket, frames)
+    trace = _active if to_trace else None
+    if trace is not None:
+        trace.add_span(name, t0_ns, t1_ns, attrs={
+            k: v for k, v in (("device", device), ("bucket", bucket),
+                              ("frames", frames)) if v >= 0})

@@ -25,8 +25,6 @@ from typing import Sequence, Tuple
 
 import numpy as np
 
-from repro import obs
-
 
 def power_of_two_buckets(max_batch: int) -> Tuple[int, ...]:
     """The default bucket ladder: 1, 2, 4, ... capped by ``max_batch``.
@@ -54,10 +52,6 @@ def pick_bucket(n: int, buckets: Sequence[int]) -> int:
         if b >= n:
             best = b
             break
-    if obs.recording():
-        obs.event("batcher.pick_bucket",
-                  attrs={"frames": n, "bucket": best,
-                         "pad": padded_slots(n, best) - n})
     return best
 
 
@@ -95,9 +89,6 @@ def split_results(out: np.ndarray, counts: Sequence[int]) -> list:
     if out.shape[0] != total:
         raise ValueError(
             f"result batch {out.shape[0]} != sum of request sizes {total}")
-    if obs.recording():
-        obs.event("batcher.split",
-                  attrs={"requests": len(counts), "frames": total})
     parts, off = [], 0
     for n in counts:
         parts.append(out[off:off + n])

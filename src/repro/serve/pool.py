@@ -54,6 +54,7 @@ import threading
 from collections import deque
 from typing import Callable, List, Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
@@ -308,10 +309,6 @@ class Pool:
             w.queued_frames += batch.n
             self._cond.notify_all()
         self._placement_us.observe((self._clock.now() - t0) * 1e6)
-        if obs.recording():
-            obs.event("serve.pool.place",
-                      attrs={"device": idx, "program": batch.hosted.name,
-                             "frames": batch.n, "bucket": batch.bucket})
         return idx
 
     # -- worker loop -------------------------------------------------------
@@ -347,6 +344,7 @@ class Pool:
                 self._cond.wait()
 
     def _run(self, w: _Worker) -> None:
+        obs.set_thread_device(w.index)     # this thread is w's lane
         pending = None                 # (batch, lazy device result)
         while True:
             nxt = self._next(w, block=pending is None)
@@ -370,7 +368,11 @@ class Pool:
 
     def _dispatch_one(self, w: _Worker, batch: Batch):
         """Async-dispatch ``batch`` on this worker's device. Returns the
-        lazy device result, or None after routing a failure to ``done``."""
+        lazy device result, or None after routing a failure to ``done``.
+        Its ``serve.batch.stage`` span runs from here to the jitted call's
+        return, with a ``pad``, ``put`` and ``launch`` span a chunk inside
+        it: ``Executable.run_padded``'s steps, timed one by one."""
+        t_stage = obs.now_ns()
         batch.t_dispatch = self._clock.now()
         with self._lock:
             w.inflight_frames += batch.n
@@ -379,7 +381,23 @@ class Pool:
         name = batch.hosted.name
 
         def default():
-            return exe.run_padded(batch.frames, batch.bucket)
+            bucket, outs = batch.bucket, []
+            t_pad = obs.now_ns()
+            for chunk, real in exe.pad_chunks(batch.frames, bucket):
+                t_put = obs.now_ns()
+                placed = exe.place(chunk)
+                t_launch = obs.now_ns()
+                out = exe.launch(placed)
+                t_end = obs.now_ns()
+                obs.span_ns("serve.batch.pad", t_pad, t_put, bucket=bucket,
+                            frames=real)
+                obs.span_ns("serve.batch.put", t_put, t_launch,
+                            bucket=bucket, frames=real)
+                obs.span_ns("serve.batch.launch", t_launch, t_end,
+                            bucket=bucket, frames=real)
+                outs.append(out[:real])
+                t_pad = obs.now_ns()
+            return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
         try:
             if self._execute_hook is not None:
@@ -389,14 +407,21 @@ class Pool:
         except Exception as e:          # noqa: BLE001 — isolate the batch
             self._fail(w, batch, e)
             return None
+        finally:
+            obs.span_ns("serve.batch.stage", t_stage, obs.now_ns(),
+                        bucket=batch.bucket, frames=batch.n)
 
     def _finish(self, w: _Worker, batch: Batch, out) -> None:
         """Block until the device result is ready; hand it to ``done``."""
+        t_wait = obs.now_ns()
         try:
             out_np = np.asarray(out)
         except Exception as e:          # noqa: BLE001 — isolate the batch
             self._fail(w, batch, e)
             return
+        finally:
+            obs.span_ns("serve.batch.wait", t_wait, obs.now_ns(),
+                        bucket=batch.bucket, frames=batch.n)
         t_ready = self._clock.now()
         with self._lock:
             w.inflight_frames -= batch.n

@@ -579,9 +579,10 @@ class Server:
 
     # -- scheduler ---------------------------------------------------------
 
-    def _collect(self) -> Optional[Tuple[HostedProgram, list, str]]:
+    def _collect(self) -> Optional[Tuple[HostedProgram, list, str, int]]:
         """One scheduling decision: pick a program, hold the batch open,
-        pop it. Returns (hosted, requests, close_reason), or None when
+        pop it. Returns (hosted, requests, close_reason, t_found_ns), the
+        last the ``now_ns()`` at which the backlog was found, or None when
         stopping with nothing left to drain."""
         cfg = self.config
         with self._cond:
@@ -594,6 +595,7 @@ class Server:
                 if self._stopping:
                     return None
                 self._cond.wait()
+            t_found = obs.now_ns()
             # route: the program whose head request has waited longest
             hosted = min(backlog, key=lambda h: h.queue[0].t_submit)
             cap = min(cfg.max_batch, max(hosted.buckets))
@@ -631,15 +633,16 @@ class Server:
             hosted.metrics.add_queued(-n)
             self._queued_total -= n
             self._cond.notify_all()        # wake backpressured submitters
-        return hosted, reqs, reason
+        return hosted, reqs, reason, t_found
 
     def _scheduler_loop(self) -> None:
         while True:
             picked = self._collect()
             if picked is None:
                 return
-            hosted, reqs, reason = picked
+            hosted, reqs, reason, t_found = picked
             t_closed = self._clock.now()   # batch stopped collecting here
+            t_closed_ns = obs.now_ns()
             if self._hooks.batch_close is not None:
                 self._hooks.batch_close(hosted.name, reason,
                                         sum(r.n for r in reqs))
@@ -670,14 +673,18 @@ class Server:
             # hand off to the pool without touching the device: placement
             # picks a worker, the worker dispatches + blocks, and the
             # completer resolves futures off the shared done queue
-            self._pool.dispatch(pool_mod.Batch(
+            device = self._pool.dispatch(pool_mod.Batch(
                 hosted, live, frames, bucket, frames.shape[0], t_closed))
+            obs.span_ns("serve.batch.collect", t_found, t_closed_ns,
+                        device=device, bucket=bucket,
+                        frames=frames.shape[0])
 
     def _completer_loop(self) -> None:
         while True:
             item = self._done.get()
             if item is _SENTINEL:
                 return
+            t_take = obs.now_ns()
             batch, live, hosted = item.batch, item.batch.live, item.batch.hosted
             try:
                 if item.error is not None:
@@ -702,26 +709,33 @@ class Server:
                 for part, req in zip(
                         batcher.split_results(item.out, [r.n for r in live]),
                         live):
-                    if not _settle(req.future, result=part):
+                    if req.future.done():
                         # a timed-out stop() already failed this request;
                         # the late completion is a no-op, not a crash
+                        continue
+                    if obs.recording():
+                        # recorded before the result is visible: a caller
+                        # that reacts to it finds its timeline recorded
+                        self._emit_request_timeline(
+                            hosted, req, batch.bucket, item.device,
+                            batch.t_closed, batch.t_dispatch, item.t_ready,
+                            self._clock.now())
+                    if not _settle(req.future, result=part):
                         continue
                     t_done = self._clock.now()
                     hosted.metrics.record_served(t_done - req.t_submit, req.n,
                                                  t_done)
                     self._observe_slo(hosted, "served", t_done,
                                       latency_ms=(t_done - req.t_submit) * 1e3)
-                    if obs.recording():
-                        self._emit_request_timeline(
-                            hosted, req, batch.bucket, item.device,
-                            batch.t_closed, batch.t_dispatch, item.t_ready,
-                            t_done)
             finally:
                 # a device is idle again: wake a scheduler holding a batch
                 # open (speculative close) and any backpressured submitters
                 with self._cond:
                     self._active_batches -= 1
                     self._cond.notify_all()
+                obs.span_ns("serve.batch.complete", t_take, obs.now_ns(),
+                            device=item.device, bucket=batch.bucket,
+                            frames=batch.n)
 
     @staticmethod
     def _emit_request_timeline(hosted: HostedProgram, req: _Request,

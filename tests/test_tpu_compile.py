@@ -96,14 +96,23 @@ def _executor_args(one_chip, program, batch, scheme):
     return exe.plan, params, frames
 
 
+def _pallas_executor(plan):
+    """The plan's per-frame executor, looked up as it is traced, inside
+    ``_compile``'s pallas context: the plan keys its executors by the
+    backend active at lookup, and one looked up outside that context may
+    carry a trace that an earlier CPU run of the same (cached) plan left,
+    with no kernel in it."""
+    return lambda *args: plan.executor(per_frame=True)(*args)
+
+
 def test_lenet_per_frame_executor_fused_chain(one_chip):
     plan, params, frames = _executor_args(
         one_chip, repro.Program.from_model("lenet"), 8, W4A4)
     assert [s.names for s in plan.fused_segments] == [("conv1", "conv2")]
-    _compile(plan.executor(per_frame=True), params, frames, plan.consts)
+    _compile(_pallas_executor(plan), params, frames, plan.consts)
 
 
 def test_vgg9_per_frame_executor_with_ca(one_chip):
     plan, params, frames = _executor_args(
         one_chip, repro.Program.from_model("vgg9"), 8, MX_43)
-    _compile(plan.executor(per_frame=True), params, frames, plan.consts)
+    _compile(_pallas_executor(plan), params, frames, plan.consts)
