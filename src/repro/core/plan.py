@@ -737,7 +737,8 @@ def _execute_steps(steps: Tuple[PlanStep, ...], params: Dict[str, Dict],
 def _execute(plan: CompiledPlan, params: Dict[str, Dict],
              frames: jnp.ndarray, per_frame: bool = False,
              donate: bool = False,
-             mesh: Optional[jax.sharding.Mesh] = None) -> jnp.ndarray:
+             mesh: Optional[jax.sharding.Mesh] = None,
+             consts: Optional[Dict] = None) -> jnp.ndarray:
     """Run ``frames`` [B, H, W, C] through a compiled plan.
 
     Returns logits [B, n] for classifier plans, or an image [B, H', W', C']
@@ -749,6 +750,11 @@ def _execute(plan: CompiledPlan, params: Dict[str, Dict],
     ``_crc_requant_traced``); the default is the seed's per-tensor
     calibration.
 
+    ``consts`` are the quantization divisors to pass the executor: the
+    plan's own numpy scalars by default, or the same values already placed
+    on the device (an ``Executable`` places them once, so a call sends
+    none).
+
     The underlying function is jitted once per plan; repeated calls with the
     same frame shape reuse the XLA executable (no re-tracing, no
     re-scheduling — the schedules live on the plan).
@@ -759,8 +765,9 @@ def _execute(plan: CompiledPlan, params: Dict[str, Dict],
         raise ValueError(f"frames {frames.shape} do not match plan frame "
                          f"shape {plan.frame_shape}; expected "
                          f"[B, {', '.join(map(str, plan.frame_shape))}]")
-    return plan.executor(per_frame, donate, mesh)(params, frames,
-                                                  plan.consts)
+    if consts is None:
+        consts = plan.consts
+    return plan.executor(per_frame, donate, mesh)(params, frames, consts)
 
 
 # ---------------------------------------------------------------------------

@@ -394,6 +394,12 @@ class Executable:
     _plan: plan_mod.CompiledPlan
     _sharded_params: Optional[Dict] = dataclasses.field(
         default=None, repr=False)
+    # the plan's quantization divisors placed once (see _placed_consts):
+    # on the bound device, or the default one, and replicated over the mesh
+    _device_consts: Optional[Dict] = dataclasses.field(
+        default=None, repr=False)
+    _sharded_consts: Optional[Dict] = dataclasses.field(
+        default=None, repr=False)
     _report_copy: Optional[pmod.ModelReport] = dataclasses.field(
         default=None, repr=False)
     _mesh: Optional[jax.sharding.Mesh] = dataclasses.field(
@@ -452,8 +458,9 @@ class Executable:
         """
         frames = jnp.asarray(frames)
         with self._pinned():
-            frames, params, mesh = self._placed(frames)
-            return plan_mod._execute(self._plan, params, frames, mesh=mesh)
+            frames, params, consts, mesh = self._placed(frames)
+            return plan_mod._execute(self._plan, params, frames,
+                                     consts=consts, mesh=mesh)
 
     def __call__(self, frames) -> jnp.ndarray:
         return self.run(frames)
@@ -471,8 +478,11 @@ class Executable:
 
         The returned Executable shares this one's compiled plan (and jit
         cache) but commits execution to ``device``: frames are
-        ``device_put`` there and the params are replicated onto it once
-        and cached. It also enables the host-side serving optimizations:
+        ``device_put`` there, and the params and the plan's quantization
+        divisors (``plan.consts``, still traced arguments of the jitted
+        executor) are placed on it once, at the first run, and cached, so
+        a launch sends no host scalar to the device. It also enables the
+        host-side serving optimizations:
 
         * ``run_padded`` pads into a **ring of reusable host staging
           buffers** per (bucket, frame-shape) instead of allocating +
@@ -512,15 +522,28 @@ class Executable:
         return exe
 
     def _placed(self, frames: jnp.ndarray):
-        """-> (frames, params, mesh) placed for this Executable: committed
-        to the bound device, or batch-sharded (mesh not None), or as is."""
+        """-> (frames, params, consts, mesh) placed for this Executable:
+        committed to the bound device, or batch-sharded (mesh not None),
+        or as is."""
         if self._device is not None:
             if self._device_params is None:
                 self._device_params = jax.device_put(self.program.params,
                                                      self._device)
             return (jax.device_put(frames, self._device),
-                    self._device_params, None)
+                    self._device_params, self._placed_consts(), None)
         return self._shard(frames)
+
+    def _placed_consts(self):
+        """The plan's quantization divisors as device arrays, placed once:
+        committed to the bound device, or uncommitted on the default
+        device (jit moves them wherever the frames are). They stay traced
+        float32 arguments (the bit-identity note in ``core.plan``); as
+        numpy scalars they were sent to the device again on every call."""
+        if self._device_consts is None:
+            self._device_consts = jax.device_put(self._plan.consts,
+                                                 self._device)
+            obs.counter("executable.consts.placed").inc()
+        return self._device_consts
 
     # -- serving: per-frame calibration + batch buckets -------------------
 
@@ -541,24 +564,25 @@ class Executable:
 
     def place(self, frames):
         """Stage a batch for :meth:`launch`: ``jnp.asarray`` and the
-        placement's ``device_put`` -> (frames, params, mesh)."""
+        placement's ``device_put`` -> (frames, params, consts, mesh)."""
         return self._placed(jnp.asarray(frames))
 
     def launch(self, placed) -> jnp.ndarray:
         """The per-frame-calibrated jitted call on a batch from
         :meth:`place`; returns the lazy device result."""
-        frames, params, mesh = placed
+        frames, params, consts, mesh = placed
         with self._pinned():
             return plan_mod._execute(self._plan, params, frames,
                                      per_frame=True, donate=self._donate,
-                                     mesh=mesh)
+                                     mesh=mesh, consts=consts)
 
     def compiled_text(self, bucket: int) -> str:
         """The compiled HLO text of the per-frame executor at batch
         ``bucket``, as :meth:`launch` runs it (compiled again, from the
         compile cache where there is one). Each instruction's ``op_name``
         names the plan step it computes (``jax.named_scope`` in
-        ``core.plan``), so a profile's op names can be read by step."""
+        ``core.plan``), so a profile's op names can be read by step. It
+        lowers with the divisors :meth:`launch` passes, placed."""
         params = (self._device_params if self._device_params is not None
                   else self.program.params)
         spec = jax.tree.map(
@@ -568,7 +592,7 @@ class Executable:
         with self._pinned():
             fn = self._plan.executor(True, self._donate, None)
             return fn.lower(spec, frames,
-                            self._plan.consts).compile().as_text()
+                            self._placed_consts()).compile().as_text()
 
     def pad_chunks(self, frames, bucket: int):
         """Yield ``(chunk, real)``: ``frames`` zero-padded into
@@ -657,23 +681,25 @@ class Executable:
     # -- batch sharding ---------------------------------------------------
 
     def _shard(self, frames: jnp.ndarray):
-        """Shard the batch axis over local devices -> (frames, params, mesh).
+        """Shard the batch axis over local devices
+        -> (frames, params, consts, mesh).
 
         No-op (mesh None) unless ``options.shard_batch``, there are >= 2
         devices, and the batch divides the device count — the
         single-device laptop path is byte-for-byte the unsharded one.
-        Params are replicated (they are small: filter taps / CNN weights),
-        frames are split on axis 0, and the executor runs per shard under
-        ``shard_map`` (see ``CompiledPlan.executor``).
+        Params and the plan's divisors are replicated once (they are
+        small: filter taps / CNN weights, scalars), frames are split on
+        axis 0, and the executor runs per shard under ``shard_map`` (see
+        ``CompiledPlan.executor``).
         """
         params = self.program.params
         if not self.options.shard_batch or frames.ndim != 4:
-            return frames, params, None
+            return frames, params, self._placed_consts(), None
         if self._mesh is None:
             mesh = self.options.mesh
             if mesh is None:
                 if len(jax.local_devices()) <= 1:
-                    return frames, params, None
+                    return frames, params, self._placed_consts(), None
                 mesh = jax.sharding.Mesh(
                     np.asarray(jax.local_devices()), ("batch",))
             self._mesh = mesh          # invariant for this Executable
@@ -683,11 +709,14 @@ class Executable:
         axis = mesh.axis_names[0]
         n = mesh.shape[axis]
         if n <= 1 or frames.shape[0] % n != 0:
-            return frames, params, None
+            return frames, params, self._placed_consts(), None
         P = jax.sharding.PartitionSpec
         frames = jax.device_put(
             frames, jax.sharding.NamedSharding(mesh, P(axis)))
         if self._sharded_params is None:
-            self._sharded_params = jax.device_put(
-                params, jax.sharding.NamedSharding(mesh, P()))
-        return frames, self._sharded_params, mesh
+            replicated = jax.sharding.NamedSharding(mesh, P())
+            self._sharded_params = jax.device_put(params, replicated)
+            self._sharded_consts = jax.device_put(self._plan.consts,
+                                                  replicated)
+            obs.counter("executable.consts.placed").inc()
+        return frames, self._sharded_params, self._sharded_consts, mesh

@@ -12,7 +12,11 @@ Contracts under test:
 * ``Program.then`` fuses two programs into ONE compiled plan whose
   quantized output tracks the float reference of the composed IR;
 * ``shard_batch`` is a graceful no-op on one device and bit-identical to
-  the unsharded path on many (subprocess with forced host devices).
+  the unsharded path on many (subprocess with forced host devices);
+* an Executable places the plan's quantization divisors on the device
+  once (a bound view on its own device, a sharded one over its mesh),
+  launches with them without a retrace after ``warm``, and lowers
+  ``compiled_text`` with them.
 """
 
 import subprocess
@@ -26,6 +30,7 @@ import numpy as np
 import pytest
 
 import repro
+from repro import obs
 from repro.core import plan as plan_mod
 from repro.core.accelerator import LightatorDevice
 from repro.core.program import Options, Program, infer_output_hwc
@@ -347,3 +352,145 @@ def test_shard_batch_multi_device_bit_identical():
                          env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-2000:]
     assert "SHARD_OK" in res.stdout
+
+
+# -- placed quantization divisors (bound and sharded views) -------------------
+
+def _assert_on(consts, device, committed=True):
+    """Every divisor is a strongly typed float32 array on ``device``
+    alone, committed there or not."""
+    leaves = jax.tree.leaves(consts)
+    assert leaves
+    for c in leaves:
+        assert isinstance(c, jax.Array) and c.committed == committed
+        assert c.devices() == {device}
+        assert c.dtype == jnp.float32 and not c.weak_type
+
+
+@pytest.mark.parametrize("bound", [True, False], ids=["bound", "unbound"])
+def test_launches_pass_placed_divisors_without_retrace(lenet, bound):
+    """After ``warm(buckets)`` launches at those buckets add no trace and
+    no jit cache entry: the warm-up already ran with the placed divisors
+    that every later launch passes, placed once. A bound view commits
+    them to its device; the unbound executable (a one-device server's)
+    leaves them uncommitted on the default device."""
+    layers, params, _ = lenet
+    exe = Program(layers, params, (28, 28, 1)).compile(
+        Options(scheme=W4A4, backend="reference"))
+    dev = jax.local_devices()[0]
+    if bound:
+        exe = exe.bind(dev)
+    placed = obs.counter("executable.consts.placed")
+    traces = obs.counter("plan.executor.traces")
+    before = placed.get()
+    exe.warm((3, 7))
+    assert placed.get() - before == 1
+    _assert_on(exe._device_consts, dev, committed=bound)
+    fn = exe.plan.executor(True, exe._donate, None)
+    n_traces, n_entries = traces.get(), fn._cache_size()
+    for b in (3, 7, 3, 7):
+        staged = exe.place(np.zeros((b, 28, 28, 1), np.float32))
+        assert staged[2] is exe._device_consts
+        np.asarray(exe.launch(staged))
+    np.asarray(exe.run_padded(np.zeros((2, 28, 28, 1), np.float32), 3))
+    assert traces.get() == n_traces and fn._cache_size() == n_entries
+    assert placed.get() - before == 1
+
+
+def test_compiled_text_lowers_with_the_placed_divisors(lenet, monkeypatch):
+    layers, params, _ = lenet
+    exe = Program(layers, params, (28, 28, 1)).compile(
+        Options(scheme=W4A4, backend="reference"))
+    bound = exe.bind(jax.local_devices()[0])
+    lowered_with = []
+    real = plan_mod.CompiledPlan.executor
+
+    class Spy:
+        def __init__(self, fn):
+            self.fn = fn
+
+        def lower(self, params, frames, consts):
+            lowered_with.append(consts)
+            return self.fn.lower(params, frames, consts)
+
+    monkeypatch.setattr(plan_mod.CompiledPlan, "executor",
+                        lambda self, *a: Spy(real(self, *a)))
+    text = bound.compiled_text(2)
+    assert "op_name" in text
+    assert bound.compiled_text(2) == text
+    assert "op_name" in exe.compiled_text(2)
+    bound_consts, again, unbound_consts = lowered_with
+    assert bound_consts is bound._device_consts and again is bound_consts
+    _assert_on(bound_consts, bound.device)
+    assert unbound_consts is exe._device_consts
+    _assert_on(unbound_consts, bound.device, committed=False)
+
+
+_PLACED_CONSTS_SCRIPT = """
+import jax, numpy as np
+import repro
+from repro import obs, serve
+from repro.core.quant import W4A4
+assert len(jax.local_devices()) == 4, jax.local_devices()
+placed = obs.counter("executable.consts.placed")
+prog = repro.Program.from_model("lenet", key=jax.random.PRNGKey(0))
+opts = repro.Options(scheme=W4A4, backend="reference")
+server = serve.Server(serve.ServeConfig(max_batch=4, max_wait_ms=0.5,
+                                        devices=4, placement="round_robin"))
+hosted = server.register("lenet", prog, opts)
+server.start(warm=True)
+rng = np.random.default_rng(5)
+frames = [rng.random((int(n), 28, 28, 1), np.float32)
+          for n in rng.integers(1, 5, 48)]
+try:
+    outs = [np.asarray(f.result(timeout=300))
+            for f in [server.submit("lenet", f) for f in frames]]
+    batches = [d["batches"] for d in server.stats()["pool"]["per_device"]]
+finally:
+    server.stop()
+assert all(b >= 2 for b in batches), batches
+# one placement per bound view, however many batches ran
+assert placed.get() == 4, placed.get()
+for view in hosted.bound:
+    for c in jax.tree.leaves(view._device_consts):
+        assert c.committed and c.devices() == {view.device}, c.devices()
+        assert c.dtype == np.float32 and not c.weak_type
+unbound = prog.compile(opts)
+for f, out in zip(frames, outs):
+    np.testing.assert_array_equal(out, np.asarray(unbound.run_per_frame(f)))
+assert placed.get() == 5, placed.get()
+# a sharded view replicates its divisors over the mesh once
+exe = prog.compile(repro.Options(scheme=W4A4, backend="reference",
+                                 shard_batch=True))
+batch = jax.random.uniform(jax.random.PRNGKey(1), (8, 28, 28, 1))
+for _ in range(3):
+    out = exe.run(batch)
+np.testing.assert_array_equal(np.asarray(out),
+                              np.asarray(unbound.run(batch)))
+for c in jax.tree.leaves(exe._sharded_consts):
+    assert len(c.sharding.device_set) == 4, c.sharding
+    assert c.sharding.is_fully_replicated, c.sharding
+assert exe._device_consts is None
+assert placed.get() == 6, placed.get()
+print("CONSTS_OK")
+"""
+
+
+def test_pool_views_place_their_divisors_on_their_own_devices():
+    """A devices=4 server on 4 forced host devices: each bound view's
+    divisors are committed to that view's device, placed once per view
+    (``executable.consts.placed`` reads 4 after many batches), and every
+    answer is bit-identical to the unbound ``run_per_frame``, which
+    places its own once; a sharded view replicates them over its mesh
+    once."""
+    import os
+    env = dict(os.environ,
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=4"),
+               PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
+                                                              ""))
+    res = subprocess.run([sys.executable, "-c", _PLACED_CONSTS_SCRIPT],
+                         cwd=Path(__file__).resolve().parent.parent,
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-2000:]
+    assert "CONSTS_OK" in res.stdout
