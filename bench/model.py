@@ -2,21 +2,27 @@
 
 The configuration (``bench/configs/<name>.json``) describes the network as
 plain data: input shape, layer list, [W:A] scheme and weight init. This
-module turns it into the program's own ``repro.Program`` (layer IR from
-``repro.core.accelerator``) with weights made on the device in one jitted
-call from the run's seed, and into a host pool of input frames drawn from
-the same seed. The plain reference (``bench/reference.py``) reads the same
-file and the same weights; nothing here is shared with it but data.
+module turns it into the program's own ``repro.Program`` (each entry's
+layer IR from the ``program`` face of its kind file, ``bench/layers``) with
+the weight tensors that the kinds' ``weights`` faces declare, made on the
+device in one jitted call from the run's seed, and into a host pool of
+input frames drawn from the same seed. The plain reference
+(``bench/reference.py``) reads the same file and the same weights; nothing
+here is shared with it but data.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, List
+from pathlib import Path
+from typing import Dict
 
 import numpy as np
 
+import cells
+
 MASK32 = 0xFFFFFFFF
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def seed_key(seed: int):
@@ -29,25 +35,14 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & MASK32)
 
 
-def weighted_layers(cfg: Dict) -> List[Dict]:
-    """The conv and dense layers, in order: the ones that hold weights."""
-    return [l for l in cfg["layers"] if l["kind"] in ("conv", "dense")]
+def param_count(cfg: Dict, root: Path = ROOT) -> int:
+    return sum(math.prod(shape) + shape[-1]
+               for _, shape in cells.tensors(root, cfg))
 
 
-def weight_shape(layer: Dict):
-    if layer["kind"] == "conv":
-        k = layer["kernel"]
-        return (k, k, layer["c_in"], layer["c_out"])
-    return (layer["fan_in"], layer["fan_out"])
-
-
-def param_count(cfg: Dict) -> int:
-    return sum(math.prod(weight_shape(l)) + weight_shape(l)[-1]
-               for l in weighted_layers(cfg))
-
-
-def make_params(cfg: Dict, seed: int):
-    """Weights and biases on the default device, in one jitted call.
+def make_params(cfg: Dict, seed: int, root: Path = ROOT):
+    """Weights and biases of every declared tensor on the default device,
+    in one jitted call.
 
     He-normal weights (std sqrt(2 / fan_in)) keep a ReLU stack's
     activations in range at any depth; biases are normal with
@@ -55,18 +50,17 @@ def make_params(cfg: Dict, seed: int):
     """
     import jax
     import jax.numpy as jnp
-    layers = weighted_layers(cfg)
+    tensors = cells.tensors(root, cfg)
     bias_std = float(cfg["init"]["bias_std"])
 
     @jax.jit
     def init(key):
-        keys = jax.random.split(key, len(layers))
+        keys = jax.random.split(key, len(tensors))
         out = {}
-        for layer, k in zip(layers, keys):
-            shape = weight_shape(layer)
+        for (name, shape), k in zip(tensors, keys):
             fan_in = math.prod(shape[:-1])
             kw, kb = jax.random.split(k)
-            out[layer["name"]] = {
+            out[name] = {
                 "w": jax.random.normal(kw, shape, jnp.float32)
                 * np.float32(math.sqrt(2.0 / fan_in)),
                 "b": jax.random.normal(kb, (shape[-1],), jnp.float32)
@@ -93,31 +87,14 @@ def scheme(cfg: Dict):
     return MixedPrecisionScheme(first, rest)
 
 
-def program_layers(cfg: Dict) -> tuple:
+def program_layers(cfg: Dict, root: Path = ROOT) -> tuple:
     """The configuration's layer list as the program's layer IR."""
-    from repro.core.accelerator import CASpec, ConvSpec, DenseSpec, FlattenSpec
-    out = []
-    for l in cfg["layers"]:
-        kind = l["kind"]
-        if kind == "ca":
-            out.append(CASpec(pool=l["pool"], rgb_to_gray=l["rgb_to_gray"]))
-        elif kind == "conv":
-            pool = tuple(l["pool"]) if l.get("pool") else None
-            out.append(ConvSpec(l["name"], l["c_in"], l["c_out"],
-                                kernel=l["kernel"], stride=l["stride"],
-                                padding=l["padding"], act=l["act"],
-                                pool=pool))
-        elif kind == "flatten":
-            out.append(FlattenSpec())
-        elif kind == "dense":
-            out.append(DenseSpec(l["name"], l["fan_in"], l["fan_out"],
-                                 act=l["act"]))
-        else:
-            raise ValueError(f"unknown layer kind {kind!r} in {cfg['name']}")
-    return tuple(out)
+    return tuple(spec for layer in cfg["layers"]
+                 for spec in cells.layer_kind(root, layer["kind"])
+                 .program(layer))
 
 
-def make_program(cfg: Dict, params):
+def make_program(cfg: Dict, params, root: Path = ROOT):
     from repro.core.program import Program
-    return Program(program_layers(cfg), params, tuple(cfg["input_hwc"]),
-                   name=cfg["name"])
+    return Program(program_layers(cfg, root), params,
+                   tuple(cfg["input_hwc"]), name=cfg["name"])

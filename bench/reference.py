@@ -3,21 +3,31 @@
 Written from the configuration file alone, in straightforward ``jax.numpy``;
 it imports nothing of the program under test. The semantics are the
 Lightator device's with per-frame calibration, the hardware's
-frame-per-pass mode that the server runs:
+frame-per-pass mode that the server runs. This module holds the steps that
+every layer kind shares, and walks the layer list; each entry's own forward
+is the ``reference`` face of its kind file, ``bench/layers/<kind>.py``
+(``bench/cells.py`` says what a kind file holds), given the activation
+codes, their per-frame scale and a ``Quant``:
 
-- CRC requant: ``x = max(x, 0)``; ``scale = max(max_frame(x), 1e-8) / 15``;
+- CRC requant (``Quant.requant``): ``x = max(x, 0)``;
+  ``scale = max(max_frame(x), 1e-8) / 15``;
   ``codes = clip(round(x / scale), 0, 15)``, the max taken over each frame.
-- Compressive acquisition: intensities ``codes * scale`` summed over the
-  ``pool x pool`` window and the three channels with weights
-  ``(0.299, 0.587, 0.114) / pool**2``, taps in (row, column, channel)
-  order, then requant.
-- Conv / dense: weights quantized per output channel,
-  ``s = max(|w|, 1e-8) / w_qmax``, ``q = clip(round(w / s), -w_qmax,
-  w_qmax)``; the accumulate of codes by levels is an exact integer sum
+  The frames enter through it.
+- Weights (``Quant.weight``), quantized per output channel:
+  ``s = max(|w|, 1e-8) / w_qmax``, ``q = clip(round(w / s), -levels,
+  levels)``. A kind accumulates codes by levels as an exact integer sum
   (bfloat16 carries both exactly, the product accumulates in float32, and
-  every sum stays under 2**24); dequant ``acc * (act_scale * s) + b``;
-  ReLU; 2x2 max pool; requant. The last dense layer's dequantized output
-  is the logits.
+  every sum stays under 2**24), then dequantizes ``acc * (act_scale * s)``,
+  adds the bias behind ``Quant.no_fma``, and applies its activation, its
+  pool (``Quant.max_pool``) and the requant, in that order.
+- Compressive acquisition (``Quant.acquire``): intensities ``codes *
+  scale`` summed over the ``pool x pool`` window and the three channels
+  with weights ``(0.299, 0.587, 0.114) / pool**2``, taps in (row, column,
+  channel) order.
+
+The output is the last entry's codes times its scale: the logits after a
+last dense layer with no activation (which returns its dequantized output
+with scale 1), an image after a spatial one.
 
 The divisors 15 and ``w_qmax`` enter as traced arguments, so the compiler
 cannot turn ``x / 15`` into ``x * (1 / 15)``, which rounds differently.
@@ -29,10 +39,16 @@ which the comparison has to reject.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from pathlib import Path
 from typing import Dict
 
 import numpy as np
+
+import cells
+
+ROOT = Path(__file__).resolve().parents[1]
 
 RGB = (0.299, 0.587, 0.114)
 
@@ -85,88 +101,76 @@ def _max_pool(y, size: int):
     return out
 
 
-def _weight_bits(cfg: Dict):
-    """w_bits per weighted layer: the first at ``scheme.first``, the rest
-    at ``scheme.rest`` (Lightator-MX)."""
-    first, rest = cfg["scheme"]["first"], cfg["scheme"]["rest"]
-    names = [l["name"] for l in cfg["layers"] if l["kind"] in ("conv", "dense")]
-    return {n: (first if i == 0 else rest)["w_bits"] for i, n in enumerate(names)}
+@dataclasses.dataclass(frozen=True)
+class Quant:
+    """What a layer kind's ``reference`` face is given beside its entry:
+    the precision of every float step, the activation divisor and code
+    range, each weight tensor's divisor and levels, and the steps every
+    kind shares. The divisors are traced arguments in ``dtype``."""
+
+    dtype: object
+    a_qmax: object
+    qmax_codes: int
+    w_qmax: Dict[str, object]
+    levels: Dict[str, int]
+
+    no_fma = staticmethod(_no_fma)
+    max_pool = staticmethod(_max_pool)
+    acquire = staticmethod(_acquire)
+
+    def requant(self, y):
+        """CRC requant of ``y``: codes and their per-frame scale."""
+        return _requant(y, self.a_qmax, self.qmax_codes)
+
+    def weight(self, params, name: str):
+        """Tensor ``name``'s weight codes and per-output-channel scale."""
+        return _quant_weight(params[name]["w"].astype(self.dtype),
+                             self.w_qmax[name].astype(self.dtype),
+                             self.levels[name])
 
 
-def forward(cfg: Dict, params, frames, a_qmax, w_qmax: Dict, dtype):
-    """Logits [B, n_classes] (float32) for float32 frames [B, H, W, C]."""
-    import jax
+def forward(cfg: Dict, params, frames, a_qmax, w_qmax: Dict, dtype,
+            root: Path = ROOT):
+    """The dequantized output (float32) for float32 frames [B, H, W, C]:
+    logits [B, n_classes] after a last dense layer, an image after a
+    spatial one. Each entry runs its kind's ``reference`` face."""
     import jax.numpy as jnp
-    bits = _weight_bits(cfg)
     a_bits = cfg["scheme"]["rest"]["a_bits"]
-    qmax_codes = (1 << a_bits) - 1
-    a_qmax = a_qmax.astype(dtype)
-    x, act_scale = _requant(frames.astype(dtype), a_qmax, qmax_codes)
+    q = Quant(dtype=dtype, a_qmax=a_qmax.astype(dtype),
+              qmax_codes=(1 << a_bits) - 1, w_qmax=w_qmax,
+              levels={n: (1 << (b - 1)) - 1
+                      for n, b in cells.weight_bits(root, cfg).items()})
+    x, act_scale = q.requant(frames.astype(dtype))
     for layer in cfg["layers"]:
-        kind = layer["kind"]
-        if kind == "ca":
-            g = _acquire(x * act_scale, layer["pool"])
-            x, act_scale = _requant(g, a_qmax, qmax_codes)
-        elif kind == "flatten":
-            flat = (x * act_scale).reshape(x.shape[0], -1)
-            x, act_scale = _requant(flat, a_qmax, qmax_codes)
-        elif kind in ("conv", "dense"):
-            p = params[layer["name"]]
-            levels = (1 << (bits[layer["name"]] - 1)) - 1
-            q, s = _quant_weight(p["w"].astype(dtype),
-                                 w_qmax[layer["name"]].astype(dtype), levels)
-            if kind == "conv":
-                k = layer["kernel"]
-                if layer["stride"] != 1:
-                    raise ValueError("the reference knows stride-1 convs only")
-                pad = (k - 1) // 2 if layer["padding"] == "SAME" else 0
-                acc = jax.lax.conv_general_dilated(
-                    x.astype(jnp.bfloat16), q.astype(jnp.bfloat16),
-                    window_strides=(layer["stride"],) * 2,
-                    padding=((pad, k - 1 - pad),) * 2,
-                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                    preferred_element_type=jnp.float32)
-                scale = act_scale * s.reshape(1, 1, 1, -1)
-            else:
-                acc = jnp.dot(x.astype(jnp.bfloat16), q.astype(jnp.bfloat16),
-                              preferred_element_type=jnp.float32)
-                scale = act_scale * s.reshape(1, -1)
-            out = _no_fma(acc.astype(dtype) * scale) + p["b"].astype(dtype)
-            if layer["act"] == "none":
-                x = out
-                continue
-            y = jnp.maximum(out, 0)
-            if layer.get("pool"):
-                y = _max_pool(y, layer["pool"][1])
-            x, act_scale = _requant(y, a_qmax, qmax_codes)
-        else:
-            raise ValueError(f"unknown layer kind {kind!r}")
-    return x.astype(jnp.float32)
+        x, act_scale = cells.layer_kind(root, layer["kind"]).reference(
+            x, act_scale, layer, params, q)
+    return (x * act_scale).astype(jnp.float32)
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(cfg_key: str, dtype_name: str):
+def _jitted(cfg_key: str, dtype_name: str, root: str):
     import json
     import jax
     import jax.numpy as jnp
     cfg = json.loads(cfg_key)
     dtype = jnp.dtype(dtype_name)
     return jax.jit(lambda params, frames, a_qmax, w_qmax:
-                   forward(cfg, params, frames, a_qmax, w_qmax, dtype))
+                   forward(cfg, params, frames, a_qmax, w_qmax, dtype,
+                           Path(root)))
 
 
 def logits(cfg: Dict, params, frames: np.ndarray, dtype: str = "float32",
-           chunk: int = 16) -> np.ndarray:
+           chunk: int = 16, root: Path = ROOT) -> np.ndarray:
     """Run the reference over ``frames`` in chunks of ``chunk`` frames
     (one compiled program; the last chunk is zero-padded), -> numpy."""
     import json
     import jax.numpy as jnp
     fn = _jitted(json.dumps({"layers": cfg["layers"],
                              "scheme": cfg["scheme"]}, sort_keys=True),
-                 dtype)
-    bits = _weight_bits(cfg)
+                 dtype, str(root))
     a_qmax = jnp.float32((1 << cfg["scheme"]["rest"]["a_bits"]) - 1)
-    w_qmax = {n: jnp.float32((1 << (b - 1)) - 1) for n, b in bits.items()}
+    w_qmax = {n: jnp.float32((1 << (b - 1)) - 1)
+              for n, b in cells.weight_bits(root, cfg).items()}
     out = []
     for off in range(0, len(frames), chunk):
         part = frames[off:off + chunk]

@@ -13,7 +13,7 @@ One run, in one process:
    traffic's batch buckets, then a short warm phase of the traffic itself;
 2. the measured window of ``--seconds``, driven through
    ``Server.submit`` by the traffic's closed or open loop;
-3. the check: every answered request's logits against the plain reference
+3. the check: every answered request's output against the plain reference
    (``bench/reference.py``) run on the same frames after the window.
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics,
@@ -304,14 +304,14 @@ def serve_config(traffic: Dict):
 
 
 def compare(cfg: Dict, params, frames: np.ndarray, log: loadgen.Log,
-            per_request: int) -> Dict:
-    """Every answered request's logits against the reference's on its own
-    frames: the worst frame's max |served - reference| over the reference
-    logits' max |.|."""
+            per_request: int, root: Path = ROOT) -> Dict:
+    """Every answered request's output against the reference's on its own
+    frames, whatever their shape (logits, an image): the worst frame's max
+    |served - reference| over the reference output's max |.|."""
     answered = [i for i, o in enumerate(log.out) if o is not None]
     ref = reference.logits(cfg, params, frames,
-                           chunk=cfg["check"]["ref_chunk"])
-    ref = ref.reshape(-1, per_request, ref.shape[-1])
+                           chunk=cfg["check"]["ref_chunk"], root=root)
+    ref = ref.reshape(-1, per_request, *ref.shape[1:])
     worst, shape_bad = 0.0, 0
     for i in answered:
         out = np.asarray(log.out[i])
@@ -319,7 +319,8 @@ def compare(cfg: Dict, params, frames: np.ndarray, log: loadgen.Log,
         if out.shape != want.shape or not np.all(np.isfinite(out)):
             shape_bad += 1
             continue
-        worst = max(worst, check.logit_err(out, want))
+        worst = max(worst, check.logit_err(out.reshape(per_request, -1),
+                                           want.reshape(per_request, -1)))
     return {"compared": len(answered) - shape_bad, "logit_err": worst,
             "malformed": shape_bad}
 
@@ -327,20 +328,22 @@ def compare(cfg: Dict, params, frames: np.ndarray, log: loadgen.Log,
 def run_cell(bench: Dict, cell: Dict, cfg: Dict, traffic: Dict, seed: int,
              seconds: float, trace: bool, options, devices, peak: Dict,
              hooks=None, t_start: float = T_START,
-             keep_trace: Optional[str] = None) -> Dict:
-    """One run of ``cell``; returns the result line without ``device``."""
+             keep_trace: Optional[str] = None, root: Path = ROOT) -> Dict:
+    """One run of ``cell``; returns the result line without ``device``.
+    ``root`` is the checkout whose ``bench/layers`` and ``bench/metrics``
+    hold the configuration's layer kinds and the cell's readers."""
     from repro.serve import AdmissionError, Server
     per_request = traffic["frames_per_request"]
     n_payloads = traffic["distinct_frames"] // per_request
     compiles = CompileCounter()
     gcw = GcWatch()
-    params = model.make_params(cfg, seed)
+    params = model.make_params(cfg, seed, root)
     frames = model.make_frames(cfg, n_payloads * per_request, seed)
     payloads = [frames[p * per_request:(p + 1) * per_request]
                 for p in range(n_payloads)]
     order = np.random.default_rng([seed, 3]).permutation(n_payloads)
 
-    program = model.make_program(cfg, params)
+    program = model.make_program(cfg, params, root)
     server = Server(serve_config(traffic), hooks=hooks)
     hosted = server.register(cfg["name"], program, options,
                              buckets=traffic["server"]["batch_buckets"])
@@ -426,7 +429,7 @@ def run_cell(bench: Dict, cell: Dict, cfg: Dict, traffic: Dict, seed: int,
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
-    got = compare(cfg, params, frames, log, per_request)
+    got = compare(cfg, params, frames, log, per_request, root)
     say(f"{loadgen.now() - t_start:.1f} s since start: trace read, "
         f"answers compared")
     limit = cfg["check"]["logit_err_limit"]
@@ -450,9 +453,9 @@ def run_cell(bench: Dict, cell: Dict, cfg: Dict, traffic: Dict, seed: int,
         ctx = {
             "config": cfg, "traffic": traffic, "chips": len(devices),
             "peak": peak, "seconds": seconds, "frames_per_s": fps,
-            "ops_per_frame": work.ops_per_frame(cfg),
+            "ops_per_frame": work.ops_per_frame(cfg, root),
             "least_time_per_frame_s":
-                work.least_time_s(cfg, bucket, peak) / bucket,
+                work.least_time_s(cfg, bucket, peak, root) / bucket,
             # each with its "seconds": the untraced host part, the part
             # the profiler recorded, the whole window
             "parts": {"host": host, "spans": watch.part("host1", "spans1"),
@@ -462,7 +465,7 @@ def run_cell(bench: Dict, cell: Dict, cfg: Dict, traffic: Dict, seed: int,
         }
         metrics = {}
         for m in cells.per_layer_metrics(bench, cell["name"]):
-            v = cells.metric_reader(ROOT, m["name"])(ctx)
+            v = cells.metric_reader(root, m["name"])(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     result = {"correct": bool(correct), "attempted": int(len(win)),

@@ -30,17 +30,19 @@ TRAFFIC = {"loop": "closed", "outstanding": 8, "frames_per_request": 1,
                       "max_queue": 64, "devices": 1}}
 
 
-def _run_cell(hooks=None, seed=2**33 + 5, traffic=TRAFFIC):
+def _run_cell(hooks=None, seed=2**33 + 5, traffic=TRAFFIC, cfg=None,
+              root=ROOT):
     import run
     from repro.core.program import Options
-    with open(TINY) as f:
-        cfg = json.load(f)
+    if cfg is None:
+        with open(TINY) as f:
+            cfg = json.load(f)
     bench = cells.load_benchmark(ROOT)
     options = Options(scheme=model.scheme(cfg)).resolve()
     peak = cells.peaks(ROOT, "TPU v5 lite")
     return run.run_cell(bench, {"name": "vgg9-offline"}, cfg, traffic, seed,
                         0.5, False, options, jax.devices()[:1], peak,
-                        hooks=hooks)
+                        hooks=hooks, root=root)
 
 
 def _env():
@@ -172,3 +174,84 @@ def test_control_fails_the_limit():
         assert r["checks"]["logit_err"]["value"] > \
             r["checks"]["logit_err"]["limit"]
         assert r["checks"]["compared"]["value"] > 8
+
+
+# A block kind as a later configuration would add it: one file, two conv
+# tensors, each conv requantized. The altered copy drops the second
+# requant, so the next conv is given float values where the program gives
+# it codes.
+PAIR_KIND = """
+def _convs(layer):
+    n, a, m, b = layer["name"], layer["c_in"], layer["c_mid"], layer["c_out"]
+    return [(n + ".a", a, m), (n + ".b", m, b)]
+
+
+def weights(layer):
+    return [(name, (3, 3, ci, co)) for name, ci, co in _convs(layer)]
+
+
+def program(layer):
+    from repro.core.accelerator import ConvSpec
+    return tuple(ConvSpec(name, ci, co) for name, ci, co in _convs(layer))
+
+
+def reference(x, scale, layer, params, q):
+    import jax
+    import jax.numpy as jnp
+    for i, (name, _, _) in enumerate(_convs(layer)):
+        w, s = q.weight(params, name)
+        acc = jax.lax.conv_general_dilated(
+            x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), (1, 1),
+            ((1, 1), (1, 1)), dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            preferred_element_type=jnp.float32)
+        out = (q.no_fma(acc.astype(q.dtype) * (scale * s.reshape(1, 1, 1, -1)))
+               + params[name]["b"].astype(q.dtype))
+        y = jnp.maximum(out, 0)
+        x, scale = q.requant(y)
+    return x, scale
+
+
+def work(hwc, layer, q):
+    h, w, _ = hwc
+    return ([{"name": name, "macs": h * w * ci * co * 9,
+              "weight_bytes": 9 * ci * co * q["w_bits"][name] / 8 + co * 4,
+              "act_bytes": h * w * co * q["a_bits"] / 8}
+             for name, ci, co in _convs(layer)], (h, w, layer["c_out"]))
+"""
+DROP_SECOND_REQUANT = (
+    "        x, scale = q.requant(y)\n",
+    "        x, scale = q.requant(y) if i == 0 else (y, jnp.ones_like(scale))\n")
+
+
+def _pair_root(tmp_path, source):
+    """A checkout whose layer kinds are the built-in ones plus ``pair``,
+    and tiny-ca with its first conv replaced by a pair (1 -> 4 -> 8)."""
+    shutil.copytree(ROOT / "bench" / "layers", tmp_path / "bench" / "layers",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench" / "layers" / "pair.py").write_text(source)
+    with open(TINY) as f:
+        cfg = json.load(f)
+    cfg["name"] = "tiny-pair"
+    cfg["layers"][1] = {"kind": "pair", "name": "pair", "c_in": 1,
+                        "c_mid": 4, "c_out": 8}
+    return cfg
+
+
+def test_a_kind_added_as_one_file_is_served_and_checked(tmp_path):
+    cfg = _pair_root(tmp_path, PAIR_KIND)
+    assert [n for n, _ in cells.tensors(tmp_path, cfg)][:2] == \
+        ["pair.a", "pair.b"]
+    assert model.param_count(cfg, tmp_path) == \
+        9802 - (9 * 8 + 8) + (9 * 4 + 4) + (9 * 4 * 8 + 8)
+    r = _run_cell(cfg=cfg, root=tmp_path)
+    assert r["correct"], r["checks"]
+    assert r["checks"]["compared"]["value"] > 8
+
+
+def test_a_kind_with_a_wrong_reference_is_not_correct(tmp_path):
+    assert DROP_SECOND_REQUANT[0] in PAIR_KIND
+    cfg = _pair_root(tmp_path, PAIR_KIND.replace(*DROP_SECOND_REQUANT))
+    r = _run_cell(cfg=cfg, root=tmp_path)
+    assert not r["correct"]
+    assert r["checks"]["logit_err"]["value"] > \
+        r["checks"]["logit_err"]["limit"]

@@ -56,3 +56,25 @@ def test_least_time_bounds(batch):
     assert t >= batch * work.ops_per_frame(cfg) / peak["int8_ops_per_s"]
     w_bytes = sum(l["weight_bytes"] for l in work.layers(cfg))
     assert t >= w_bytes / peak["hbm_bytes_per_s"]
+
+
+V5E = {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}
+
+
+@pytest.mark.parametrize("path,ops,params,least", [
+    ("configs/vgg16-224-mx43.json", 30_940_528_640, 138_357_544,
+     {8: 0.0006995687019760087}),
+    ("configs/vgg9ca-32-mx43.json", 77_473_792, 1_983_012,
+     {64: 1.4366099253269208e-05, 16: 3.883788549581038e-06}),
+    ("tests/data/tiny-ca.json", 175_232, 9_802, {}),
+])
+def test_counts_are_pinned(path, ops, params, least):
+    """The counts the per-layer metrics divide by, exactly as the layer
+    list gave them before the layer kinds were files of their own."""
+    import model
+    with open(ROOT / "bench" / path) as f:
+        cfg = json.load(f)
+    assert work.ops_per_frame(cfg) == ops
+    assert model.param_count(cfg) == params
+    for batch, t in least.items():
+        assert work.least_time_s(cfg, batch, V5E) == t
